@@ -117,6 +117,7 @@ from repro.launch.engine import (
     run_static_baseline,
     solo_generate,
 )
+from repro.local_cache import use_compile_cache
 from repro.models import lm
 
 
@@ -915,6 +916,7 @@ def main():
              "(artifact: engine_bench_slo.json)",
     )
     args = ap.parse_args()
+    use_compile_cache()
     run(mesh_lane=args.mesh, faults_lane=args.faults,
         overload_lane=args.overload, slo_lane=args.slo, spec_lane=args.spec)
 

@@ -43,7 +43,9 @@ __all__ = [
     "ChipModel",
     "TPU_V5E",
     "INTERPRET_CPU",
+    "CHIPS",
     "chip_for_backend",
+    "chip_for_kind",
 ]
 
 
@@ -161,9 +163,32 @@ INTERPRET_CPU = ChipModel(
 )
 
 
+# compiled-backend chip constants, keyed by jax's ``Device.device_kind``
+CHIPS: Dict[str, ChipModel] = {
+    "TPU v5 lite": TPU_V5E,  # v5e (Google Cloud "TPU v5e" documentation)
+}
+
+
+def chip_for_kind(device_kind: str) -> ChipModel:
+    """The chip constants for a device kind; a device that is not in
+    :data:`CHIPS` is an error, never a default."""
+    try:
+        return CHIPS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no chip constants for device kind {device_kind!r}; "
+            f"known: {sorted(CHIPS)}"
+        ) from None
+
+
 def chip_for_backend(interpret: bool) -> ChipModel:
-    """The chip whose roofline terms model the resolved kernel backend."""
-    return INTERPRET_CPU if interpret else TPU_V5E
+    """The chip whose roofline terms model the resolved kernel backend: the
+    interpreter's stand-in, or the constants of the device JAX runs on."""
+    if interpret:
+        return INTERPRET_CPU
+    import jax
+
+    return chip_for_kind(jax.devices()[0].device_kind)
 
 
 def calibrated_table() -> Dict[str, Dict[str, float]]:

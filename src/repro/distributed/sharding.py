@@ -74,10 +74,12 @@ def serve_rules(cfg: ModelConfig, mesh: Mesh, *, seq_shard_kv: bool = False,
     everywhere and the batch (slot) axis claims EVERY mesh axis, so each
     device owns a contiguous block of slots end-to-end.  No contraction ever
     crosses a shard boundary, which makes mesh decode bit-exact against a
-    single device (TP's partitioned wo/mlp reductions reassociate the bf16
-    sums — ~1 ulp logit wobble, enough to flip a greedy argmax; see
-    docs/serving.md).  Use it when the model fits one chip and the pool is
-    what needs scaling — the slot-parity acceptance tests run in this mode.
+    single device on the host CPU backend (TP's partitioned wo/mlp
+    reductions reassociate the bf16 sums — ~1 ulp logit wobble, enough to
+    flip a greedy argmax; see docs/serving.md).  On a TPU the compiler's
+    layouts still differ between the two programs, so tokens agree up to
+    near-ties.  Use it when the model fits one chip and the pool is what
+    needs scaling — the slot-parity acceptance tests run in this mode.
     """
     if replicate_params:
         rules: Rules = {

@@ -34,7 +34,9 @@ def _kernel(x_ref, o_ref, *, rsqrt: bool):
     res = numerics.compose(jnp.zeros_like(sign), exp_out, man_out, fmt)
     res = numerics.apply_specials(res, x, sign, exp, man, fmt)
     if rsqrt:
-        is_zero = (exp == 0) & (man == 0)
+        # as core.e2afs.e2afs_rsqrt: a flushed positive subnormal is zero to
+        # the datapath, so it takes rsqrt(0) = +inf too
+        is_zero = (exp == 0) & ((man == 0) | (sign == 0))
         is_inf = (exp == fmt.exp_mask) & (man == 0) & (sign == 0)
         res = jnp.where(is_zero, jnp.array(jnp.inf, res.dtype), res)
         res = jnp.where(is_inf, jnp.zeros_like(res), res)

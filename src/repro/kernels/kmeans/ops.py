@@ -37,14 +37,21 @@ def _geometry(args):
     memory contract — a per-tile (block_n, K, 3) working set far below the
     broadcast path's N-proportional footprint — against a prior that would
     otherwise pick one whole-input tile for mid-size images and degenerate
-    to exactly the (N, K, 3) materialization the kernel exists to avoid."""
+    to exactly the (N, K, 3) materialization the kernel exists to avoid.
+
+    In VMEM each pixel row pads to a 128-lane row, and the per-tile
+    distance work grows with K: for a described v5e, Mosaic reports
+    18.9 MiB at 2048 rows for K=20 and 23.8 MiB for K=64, which
+    ``vmem_tiles`` covers with a little room."""
     px, cent = args[0], args[1]
     n = int(px.shape[0])
+    k = int(cent.shape[0])
     return {
         "rows": n,
         "row_elems": max(int(px.size) // max(n, 1), 1),
-        "ops_per_elem": 3.0 * cent.shape[0],  # per channel: diff/mul/add x K
+        "ops_per_elem": 3.0 * k,  # per channel: diff/mul/add x K
         "streams": 2,
+        "vmem_tiles": 17 + -(-k // 8),
         "max_block_rows": max(n // 4, 128),
     }
 

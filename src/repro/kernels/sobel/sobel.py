@@ -4,7 +4,8 @@ The paper's §4.1 pipeline as one fused kernel: per output tile, the 3x3
 stencil (shift-adds — Sobel taps are +-1/+-2, multiplier-free like the
 sqrt), the squared magnitude, and the E2AFS integer-datapath sqrt all run
 in VMEM.  The image is small enough to sit in VMEM whole; output is tiled
-and each tile loads its (bh+2, bw+2) halo window with pl.load.
+and each tile reads its (bh+2, bw+2) halo window by indexing the ref with
+pl.ds windows.
 """
 from __future__ import annotations
 
@@ -23,7 +24,7 @@ __all__ = ["sobel_kernel_call"]
 def _kernel(img_ref, o_ref, *, bh: int, bw: int):
     i = pl.program_id(0)
     j = pl.program_id(1)
-    win = pl.load(img_ref, (pl.dslice(i * bh, bh + 2), pl.dslice(j * bw, bw + 2)))
+    win = img_ref[pl.ds(i * bh, bh + 2), pl.ds(j * bw, bw + 2)]
     # 3x3 Sobel taps via shifted adds (weights are powers of two)
     c = lambda di, dj: win[di : di + bh, dj : dj + bw]
     gx = (c(0, 2) - c(0, 0)) + 2.0 * (c(1, 2) - c(1, 0)) + (c(2, 2) - c(2, 0))

@@ -18,7 +18,7 @@ A tiling choice is resolved in three steps (DESIGN.md "Autotune cache"):
    grid steps were pure launch overhead.  The same plan narrows the sweep:
    step 2 only times the admissible candidates, not the blind grid.
 
-The cache lives at ``~/.cache/repro/kernel_tune.json`` unless
+The cache lives at ``.cache/kernel_tune.json`` in the checkout unless
 ``REPRO_TUNE_CACHE`` points elsewhere.  Sweeps never run under tracing
 (arguments are abstract, so there is nothing to time); the prior, being
 pure shape arithmetic, still resolves there.
@@ -34,6 +34,8 @@ from typing import Callable, Optional, Sequence
 
 import jax
 
+from repro.local_cache import CACHE_DIR
+
 __all__ = [
     "OCC_FLOOR",
     "autotune_enabled",
@@ -48,7 +50,7 @@ __all__ = [
 
 ENV_CACHE = "REPRO_TUNE_CACHE"
 ENV_AUTOTUNE = "REPRO_AUTOTUNE"
-DEFAULT_CACHE = "~/.cache/repro/kernel_tune.json"
+DEFAULT_CACHE = CACHE_DIR / "kernel_tune.json"
 CACHE_VERSION = 1
 
 # minimum predicted busy fraction (tile work / total incl. launch overhead)
@@ -170,23 +172,35 @@ def tile_geometry(args: Sequence) -> dict:
     }
 
 
+# a VMEM tile is laid out in whole rows of this many 32-bit lanes
+_LANES = 128
+
+
 def predict_block_time(block: Sequence[int], geom: dict, chip):
     """Predicted (seconds, occupancy, vmem_feasible) for one block candidate.
 
     The model is the per-kernel analogue of the repo's roofline tables:
     tile work = max(compute term, HBM term) over the *padded* element count
     (a clamped block never pads past one tile), plus a fixed per-grid-step
-    launch overhead.  Occupancy is the busy fraction work / total."""
+    launch overhead.  Occupancy is the busy fraction work / total.
+
+    VMEM feasibility counts what Mosaic allocates per grid step: the tile
+    padded to whole 128-lane rows at 4 bytes an element, times
+    ``vmem_tiles`` — by default two pipeline buffers for each of the
+    ``streams``; kernels whose body holds tile-sized temporaries register a
+    larger count.  The budget is strict: Mosaic adds a few KiB of its own."""
     rows, width = geom["rows"], geom["row_elems"]
+    streams = geom.get("streams", 2)
     b0 = max(1, min(int(block[0]), rows))  # wrappers clamp oversize blocks
     steps = math.ceil(rows / b0)
     elems = steps * b0 * width  # padded: grid work includes the pad waste
     compute_s = elems * geom["ops_per_elem"] / chip.peak_flops
-    memory_s = elems * 4.0 * geom.get("streams", 2) / chip.hbm_bw
+    memory_s = elems * 4.0 * streams / chip.hbm_bw
     work = max(compute_s, memory_s)
     total = work + steps * chip.step_overhead_s
     occupancy = work / total if total > 0.0 else 0.0
-    feasible = b0 * width * 4.0 * geom.get("streams", 2) <= chip.vmem_bytes
+    tile_bytes = b0 * -(-width // _LANES) * _LANES * 4.0
+    feasible = tile_bytes * geom.get("vmem_tiles", 2 * streams) < chip.vmem_bytes
     # a geometry may cap the tile below what VMEM admits — e.g. kmeans,
     # whose whole point is a working set that stays a fraction of the input
     feasible = feasible and int(block[0]) <= geom.get("max_block_rows", int(block[0]))
@@ -208,11 +222,12 @@ def roofline_plan(
     problems) the floor is waived and ties break toward the smallest block,
     which keeps tiny-input picks at the TilingSpec default.  Any modeling
     failure (no array argument, exotic shapes) falls back to the blind
-    grid."""
+    grid.  A compiled backend on a device with no chip constants is an
+    error, not a modeling failure."""
     cands = tuple(tuple(c) for c in candidates)
+    chip = _hw_model().chip_for_backend(interpret)
     try:
         geom = (geometry or tile_geometry)(args)
-        chip = _hw_model().chip_for_backend(interpret)
         scored = []
         for cand in cands:
             t, occ, ok = predict_block_time(cand, geom, chip)

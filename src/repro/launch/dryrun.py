@@ -116,17 +116,14 @@ def lower_cell(arch: str, shape_name: str, mesh_kind: str, *, quantized_kv=None,
     n_chips = mesh.size
     t0 = time.time()
 
+    # lm.init gives serving weights in the activation dtype
     params_s, specs = lm.init(cfg, jax.random.key(0), abstract=True)
-    if case.kind in ("prefill", "decode"):
-        # serving stores bf16 weights (fp32 masters are a training artifact)
-        params_s = jax.tree.map(
-            lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16)
-            if s.dtype == jnp.float32 and s.ndim >= 1
-            else s,
-            params_s,
-        )
 
     if case.kind == "train":
+        # training steps fp32 master weights (launch/train.py)
+        params_s = jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, jnp.float32), params_s
+        )
         rules = train_rules(cfg, mesh, seq_parallel=seq_parallel)
         p_sh = shardings_for(specs, mesh, rules, params_s)
         opt_s = {

@@ -8,8 +8,10 @@ reuses them; no second full-size cache is ever alive).  The per-token
 Python loop survives behind ``mode="loop"`` as the correctness baseline —
 the parity tests hold the fast path token-exact against it.
 
-Smoke-scale on CPU; the same steps lower under the production mesh in the
-dry-run.  Supports the int8-quantized cache."""
+Toy widths by default (``smoke=True``); ``smoke=False`` (CLI
+``--published-widths``) serves the architecture's published config.  The
+same steps lower under the production mesh in the dry-run.  Supports the
+int8-quantized cache."""
 from __future__ import annotations
 
 import argparse
@@ -20,7 +22,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import get_smoke_config
+from repro.configs import get_config, get_smoke_config
+from repro.local_cache import use_compile_cache
 from repro.models import lm
 
 MODES = ("scan", "loop")
@@ -48,8 +51,9 @@ def decode_loop(decode, params, cache, tok, start, gen_len):
 
 def generate(arch="qwen3-4b", *, batch=2, prompt_len=8, gen_len=16,
              sqrt_unit="e2afs", quantized_kv=False, seed=0, mode="scan",
-             reps=3, verbose=True, mesh=None, rules=None):
-    """Prefill a random prompt and greedily decode ``gen_len`` tokens.
+             reps=3, verbose=True, mesh=None, rules=None, smoke=True):
+    """Prefill a random prompt and greedily decode ``gen_len`` tokens with
+    ``arch``'s toy config (``smoke=True``) or its published one.
 
     mode="scan" (default) is the fast path; mode="loop" the per-token
     baseline.  Compilation is warmed up on a throwaway cache before the
@@ -73,7 +77,7 @@ def generate(arch="qwen3-4b", *, batch=2, prompt_len=8, gen_len=16,
             f"prompt_len must be >= 1 (got {prompt_len}): prefill needs at "
             f"least one prompt token to produce first-step logits"
         )
-    cfg = get_smoke_config(arch, sqrt_unit=sqrt_unit)
+    cfg = (get_smoke_config if smoke else get_config)(arch, sqrt_unit=sqrt_unit)
     # MoE prefill routes with a sequence-level expert capacity, so scan-mode
     # greedy tokens may differ from the per-token loop (lm.prefill docs);
     # every other stack is held token-exact by the parity suite
@@ -171,10 +175,14 @@ def main():
     ap.add_argument("--quantized-kv", action="store_true")
     ap.add_argument("--mode", choices=MODES, default="scan",
                     help="scan: fused prefill + scan decode; loop: per-token baseline")
+    ap.add_argument("--published-widths", action="store_true",
+                    help="serve the architecture's published config, not its toy one")
     args = ap.parse_args()
+    use_compile_cache()
     toks, _ = generate(args.arch, batch=args.batch, prompt_len=args.prompt_len,
                        gen_len=args.gen_len, sqrt_unit=args.sqrt_unit,
-                       quantized_kv=args.quantized_kv, mode=args.mode)
+                       quantized_kv=args.quantized_kv, mode=args.mode,
+                       smoke=not args.published_widths)
     print(toks[:, :24])
 
 

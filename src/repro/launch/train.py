@@ -44,6 +44,9 @@ def build(arch: str, *, smoke: bool, seq: int, batch: int, sqrt_unit: str,
     :func:`train_loop` iterates, reusable for custom loops."""
     cfg = (get_smoke_config if smoke else get_config)(arch, sqrt_unit=sqrt_unit)
     params, specs = lm.init(cfg, jax.random.key(0))
+    # the optimizer steps fp32 master weights; the forward casts them to
+    # the activation dtype at each use
+    params = jax.tree.map(lambda p: p.astype(jnp.float32), params)
     opt_cfg = AdamWConfig(sqrt_unit=sqrt_unit, **(opt_overrides or {}))
     opt_state = adamw_init(params)
     if compress:

@@ -33,7 +33,8 @@ class DenseInit:
     """Accumulates (params, specs) pairs with a split PRNG stream.
 
     ``abstract=True`` produces ShapeDtypeStructs instead of arrays (used by
-    the dry-run: full-size configs are never materialized)."""
+    the dry-run: full-size configs are never materialized).  Random inits
+    draw in fp32 and cast to ``dtype``."""
 
     def __init__(self, key, dtype=jnp.float32, abstract=False):
         self._key = key
@@ -47,6 +48,10 @@ class DenseInit:
             return self._key
         self._key, sub = jax.random.split(self._key)
         return sub
+
+    def child(self) -> "DenseInit":
+        """A sub-initializer on the next key, with the same dtype and mode."""
+        return DenseInit(self._next(), self.dtype, self.abstract)
 
     def add(self, name, shape, axes, init=truncated_normal, scale=1.0, dtype=None):
         assert len(shape) == len(axes), (name, shape, axes)

@@ -116,9 +116,9 @@ def exact_twin(cfg: ModelConfig) -> ModelConfig:
 
 
 def _layer_init(cfg: ModelConfig, block: str, key, *, cross: bool = False, abstract=False):
-    ini = DenseInit(key, abstract=abstract)
+    ini = DenseInit(key, _act_dtype(cfg), abstract=abstract)
     _norm_init(ini, "ln1", cfg)
-    sub_init = lambda: DenseInit(ini._next(), abstract=abstract)
+    sub_init = ini.child
     if block in ("global", "window"):
         a = sub_init()
         attn.attention_init(a, cfg)
@@ -208,13 +208,13 @@ def _remat_wrapper(cfg):
 
 
 def _enc_layer_init(cfg, key, *, abstract=False):
-    ini = DenseInit(key, abstract=abstract)
+    ini = DenseInit(key, _act_dtype(cfg), abstract=abstract)
     _norm_init(ini, "ln1", cfg)
-    a = DenseInit(ini._next(), abstract=abstract)
+    a = ini.child()
     attn.attention_init(a, cfg)
     ini.sub("attn", *a.build())
     _norm_init(ini, "ln2", cfg)
-    m = DenseInit(ini._next(), abstract=abstract)
+    m = ini.child()
     mlp_init(m, cfg)
     ini.sub("mlp", *m.build())
     return ini.build()
@@ -242,7 +242,9 @@ def _sinusoidal(n, d):
 
 
 def _stacked_init(init_fn, key, n, *, abstract=False):
-    """vmap an init over n layers -> params with leading 'layers' axis."""
+    """Stack a per-layer init over n layers (leading 'layers' axis).
+    Abstract: (shapes, logical specs).  Concrete: (vmapped params, None) —
+    the specs come from the abstract pass."""
     if abstract:
         layer, specs = init_fn(key)
         params = jax.tree.map(
@@ -256,25 +258,34 @@ def _stacked_init(init_fn, key, n, *, abstract=False):
         )
         return params, specs
     keys = jax.random.split(key, n)
-    params = jax.vmap(lambda k: init_fn(k)[0])(keys)
-    _, specs = init_fn(key)
-    specs = jax.tree.map(
-        lambda s: ("layers", *s),
-        specs,
-        is_leaf=lambda s: isinstance(s, tuple) and all(isinstance(e, (str, type(None))) for e in s),
-    )
-    return params, specs
+    return jax.vmap(lambda k: init_fn(k)[0])(keys), None
 
 
 def init(cfg: ModelConfig, key, *, abstract: bool = False):
     """Initialize a model from its config.  Returns ``(params, specs)``:
-    ``params`` the parameter pytree (uniform stacks carry a leading
-    'layers' axis for the scanned forward), ``specs`` the matching tree of
-    logical-axis tuples that ``distributed.shardings_for`` maps onto a
-    mesh.  ``abstract=True`` returns ShapeDtypeStructs instead of arrays —
-    free, for deriving shardings or dry-run lowering."""
+    ``params`` the parameter pytree in the activation dtype (uniform stacks
+    carry a leading 'layers' axis for the scanned forward), ``specs`` the
+    matching tree of logical-axis tuples that ``distributed.shardings_for``
+    maps onto a mesh.  ``abstract=True`` returns ShapeDtypeStructs instead
+    of arrays — free, for deriving shardings or dry-run lowering.
+
+    Concrete params are drawn and cast inside one jitted program, so the
+    fp32 draws of a full layer stack never outlive it on the device.
+    Training casts the result to fp32 master weights itself."""
     cfg.validate()
-    ini = DenseInit(key, abstract=abstract)
+    shapes, specs = _init_tree(cfg, key, abstract=True)
+    if abstract:
+        return shapes, specs
+    return _init_params(cfg, key), specs
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _init_params(cfg: ModelConfig, key):
+    return _init_tree(cfg, key, abstract=False)[0]
+
+
+def _init_tree(cfg: ModelConfig, key, *, abstract: bool):
+    ini = DenseInit(key, _act_dtype(cfg), abstract=abstract)
     vp = cfg.padded_vocab
     ini.add("embed", (vp, cfg.d_model), ("vocab", "embed"), scale=float(np.sqrt(cfg.d_model)))
     if not cfg.tie_embeddings:
@@ -302,7 +313,7 @@ def init(cfg: ModelConfig, key, *, abstract: bool = False):
         enc_fn = lambda k: _enc_layer_init(cfg, k, abstract=abstract)
         pe, se = _stacked_init(enc_fn, ini._next(), cfg.encoder.n_layers, abstract=abstract)
         ini.sub("encoder", pe, se)
-        e2 = DenseInit(ini._next(), abstract=abstract)
+        e2 = ini.child()
         _norm_init(e2, "enc_ln_f", cfg)
         pp, ss = e2.build()
         ini.sub("enc_extra", pp, ss)

@@ -51,6 +51,15 @@ class TestE2AFSSqrtKernel:
         assert out[0] == 0.0 and np.isinf(out[1]) and np.isnan(out[2]) and np.isnan(out[3])
         assert out[4] == 2.0
 
+    def test_rsqrt_specials_match_core_datapath(self):
+        """Zeros and subnormals of either sign, infinities, NaN: the kernel
+        takes the core datapath's flush-to-zero rsqrt policy."""
+        x = jnp.asarray([0.0, -0.0, 1e-40, -1e-40, 1e-39, jnp.inf, -jnp.inf,
+                         jnp.nan, 4.0], jnp.float32)
+        np.testing.assert_array_equal(
+            np.asarray(sqrt_ops.rsqrt(x)), np.asarray(ref_rsqrt(x))
+        )
+
 
 class TestRMSNormKernel:
     @pytest.mark.parametrize("rows,d", [(4, 128), (16, 512), (7, 384), (1, 2048)])

@@ -104,6 +104,32 @@ class TestRooflinePrior:
         assert prior[0] < 2048
         assert all(c[0] <= 2048 // 4 for c in admissible)
 
+    def test_chip_constants_keyed_by_device_kind(self):
+        """A compiled backend takes the constants of the device JAX runs on;
+        a device with none is an error, never the v5e by default."""
+        from repro.core import hw_model
+
+        assert hw_model.chip_for_kind("TPU v5 lite") is hw_model.TPU_V5E
+        with pytest.raises(ValueError, match="no chip constants"):
+            hw_model.chip_for_kind("cpu")
+        with pytest.raises(ValueError, match="no chip constants"):
+            tuning.roofline_plan([(8,), (16,)], (8,), _rmsnorm_args(64, 256),
+                                 interpret=False)
+
+    def test_vmem_check_counts_both_pipeline_buffers(self):
+        """A tile fits VMEM only if every stream's two pipeline buffers do:
+        adam's registered 7 streams refuse a tile that 2 streams admit."""
+        from repro.core.hw_model import TPU_V5E
+
+        spec = dispatch.get("adam")
+        p = jnp.zeros((2560 * 9728,), jnp.float32)
+        geom = spec.tiling.geometry((p, p, p, p))
+        assert geom["streams"] == 7
+        _, _, ok_adam = tuning.predict_block_time((256,), geom, TPU_V5E)
+        _, _, ok_two = tuning.predict_block_time(
+            (256,), {**geom, "streams": 2, "vmem_tiles": 4}, TPU_V5E)
+        assert ok_two and not ok_adam
+
     def test_modeling_failure_falls_back_to_blind_grid(self):
         prior, admissible = tuning.roofline_plan(
             [(8,), (16,)], (8,), ("not", "arrays"), interpret=True,

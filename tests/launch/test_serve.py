@@ -58,3 +58,22 @@ def test_mesh_scan_matches_loop_token_exact():
 def test_mesh_rejects_loop_mode():
     with pytest.raises(ValueError, match="scan"):
         generate("qwen3-4b", mode="loop", mesh=object(), verbose=False)
+
+
+def test_published_widths_flag_serves_the_published_config(monkeypatch, capsys):
+    """`--published-widths` asks the registry for the published config (a
+    toy one stands in for it here) and sets up the compile cache first."""
+    import sys
+
+    from repro.configs import get_smoke_config
+    from repro.launch import serve
+
+    calls = []
+    monkeypatch.setattr(serve, "get_config", lambda arch, **kw: calls.append(
+        ("published", arch)) or get_smoke_config(arch, **kw))
+    monkeypatch.setattr(serve, "use_compile_cache", lambda: calls.append("cache"))
+    monkeypatch.setattr(sys, "argv", ["serve", "--published-widths", "--batch", "1",
+                                      "--prompt-len", "3", "--gen-len", "2"])
+    serve.main()
+    assert calls == ["cache", ("published", "qwen3-4b")]
+    assert "[serve] qwen3-4b" in capsys.readouterr().out

@@ -6,14 +6,18 @@ the kill with ``run(max_chunks=k)``; this smoke closes the remaining gap by
 actually killing a serving *process* — no atexit, no flush, no interpreter
 teardown — and proving the snapshot + write-ahead journal recover it:
 
-1. the parent computes the uninterrupted reference (solo greedy tokens per
-   request — the slot-parity anchor) in-process;
-2. a child process serves the same trace with ``snapshot_every_chunks=1``
-   and a journal, and is ``SIGKILL``ed as soon as the journal shows decode
+1. a child process serves the trace with ``snapshot_every_chunks=1`` and a
+   journal, and is ``SIGKILL``ed as soon as the journal shows decode
    progress;
+2. the parent computes the uninterrupted reference (solo greedy tokens per
+   request — the slot-parity anchor) in-process;
 3. the parent resumes from whatever the dead child left on disk, drains,
    and audits the journal: every request finished EXACTLY once, tokens
    bit-equal the reference.
+
+One process holds the device at a time: the parent does not touch JAX
+until its serving child is dead (on an accelerator, a child started by a
+parent that holds the chip fails or hangs).
 
 If the child finishes before the kill lands (fast machine), the run is
 still a valid — if weaker — recovery check and the audit must still pass.
@@ -108,17 +112,6 @@ def main() -> int:
     workdir.mkdir(parents=True, exist_ok=True)
     jpath = workdir / "journal.jsonl"
 
-    from repro.launch.engine import Engine, solo_generate
-    from repro.launch.journal import read_journal, replay_plan
-
-    cfg, params, reqs = _setup()
-    print(f"[parent] reference: {len(reqs)} solo runs ({ARCH})")
-    ref = {
-        r.uid: solo_generate(params, cfg, r.prompt, r.max_new_tokens,
-                             cache_len=CACHE_LEN)
-        for r in reqs
-    }
-
     env = dict(os.environ)
     src = str(Path(__file__).resolve().parents[1] / "src")
     env["PYTHONPATH"] = src + (
@@ -145,6 +138,18 @@ def main() -> int:
         print("[parent] FAIL: child made no journaled progress before timeout")
         return 1
     print(f"[parent] child {'SIGKILLed mid-serve' if killed else 'finished before kill'}")
+
+    # the child is dead: the device is free for the parent from here on
+    from repro.launch.engine import Engine, solo_generate
+    from repro.launch.journal import read_journal, replay_plan
+
+    cfg, params, reqs = _setup()
+    print(f"[parent] reference: {len(reqs)} solo runs ({ARCH})")
+    ref = {
+        r.uid: solo_generate(params, cfg, r.prompt, r.max_new_tokens,
+                             cache_len=CACHE_LEN)
+        for r in reqs
+    }
 
     pre_kill = sum(
         1 for r in read_journal(jpath) if r["kind"] == "finished"
