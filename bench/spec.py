@@ -1,0 +1,67 @@
+"""Finds everything by name: cells, configurations, mixes, metrics, limits.
+
+``root`` is a checkout's root: ``BENCHMARK.json`` beside ``bench/``.  A cell
+is an entry of ``workloads``; its configuration is
+``bench/configs/<config>.json``, its mix ``bench/traffic/<traffic>.json``,
+its correctness limits ``bench/limits/<cell>.json``, and each per-layer
+metric ``bench/metrics/<metric>.py``.  Adding any of them is adding a file.
+"""
+from __future__ import annotations
+
+import importlib.util
+import json
+from pathlib import Path
+
+__all__ = ["ROOT", "load", "cell", "metric_reader"]
+
+ROOT = Path(__file__).resolve().parents[1]
+
+
+def _json(path: Path) -> dict:
+    with open(path, encoding="utf-8") as f:
+        return json.load(f)
+
+
+def load(root: Path = ROOT) -> dict:
+    return _json(Path(root) / "BENCHMARK.json")
+
+
+def cell(name: str, root: Path = ROOT) -> dict:
+    """One cell, resolved: its entry, configuration, mix, limits and the
+    metrics it reports at each trace setting."""
+    root = Path(root)
+    bench = load(root)
+    entries = {w["name"]: w for w in bench["workloads"]}
+    if name not in entries:
+        raise KeyError(f"no workload {name!r}; known: {sorted(entries)}")
+    w = entries[name]
+    configs = {c["name"]: c for c in bench["configs"]}
+    cfg_entry = configs[w["config"]]
+    config = _json(root / cfg_entry["file"])
+
+    def applies(metric):
+        return "workloads" not in metric or name in metric["workloads"]
+
+    e2e = [m for m in bench["end_to_end"] if applies(m)]
+    e2e_names = {m["name"] for m in e2e}
+    per_layer = [m for m in bench["per_layer"]
+                 if applies(m) and m["moves"] in e2e_names]
+    return {
+        "name": name,
+        "entry": w,
+        "config": config,
+        "traffic": _json(root / "bench" / "traffic" / f"{w['traffic']}.json"),
+        "limits": _json(root / "bench" / "limits" / f"{name}.json"),
+        "end_to_end": e2e,
+        "per_layer": per_layer,
+        "root": root,
+    }
+
+
+def metric_reader(name: str, root: Path = ROOT):
+    """The ``read(ctx)`` function of ``bench/metrics/<name>.py``."""
+    path = Path(root) / "bench" / "metrics" / f"{name}.py"
+    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
