@@ -1,0 +1,12 @@
+"""Decode attention (``layers/attention.py`` ``attention_decode`` under
+``jax.named_scope("decode_attention")``: the cache write and read, the KV
+expansion, scores, softmax and weighted sum): device time of its leaf
+operations in the decode program per decode step, in ms
+(bench/program_trace.py).  Reads the trace's ``scopes``.  Moves
+``tpot_p90_ms``."""
+from bench import program_trace
+
+
+def read(ctx):
+    split = program_trace.decode_split(ctx)
+    return None if split is None else split["decode_attention"]

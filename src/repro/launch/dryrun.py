@@ -87,7 +87,7 @@ def _decode_hbm_estimate_gib(cfg, case, mesh) -> float:
 
 def lower_cell(arch: str, shape_name: str, mesh_kind: str, *, quantized_kv=None,
                sqrt_unit="e2afs", microbatches=1, seq_parallel=False,
-               extra_overrides=None, smoke=False, attribute_top=0):
+               extra_overrides=None, smoke=False):
     """Lower + compile one cell; returns the result record (dict).
 
     quantized_kv=None -> policy: quantize the KV cache (int8, the framework's
@@ -258,12 +258,6 @@ def lower_cell(arch: str, shape_name: str, mesh_kind: str, *, quantized_kv=None,
         "microbatches": microbatches,
         "seq_parallel": seq_parallel,
     }
-    if attribute_top:
-        from repro.launch.attribution import attribute
-
-        top_bytes, top_flops = attribute(hlo_text, top=attribute_top)
-        rec["top_bytes"] = top_bytes
-        rec["top_flops"] = top_flops
     dom = max(rec["roofline"], key=rec["roofline"].get)
     rec["roofline"]["dominant"] = dom
     return rec
@@ -288,8 +282,6 @@ def main():
     ap.add_argument("--seq-parallel", action="store_true")
     ap.add_argument("--smoke", action="store_true", help="reduced configs on a 2x2[x2] mesh")
     ap.add_argument("--remat", default=None, choices=("none", "block", "minimal"))
-    ap.add_argument("--attribute", type=int, default=0, metavar="N",
-                    help="record top-N byte/flop instructions in the JSON")
     ap.add_argument("--tag", default="")
     ap.add_argument("--out", default="experiments/dryrun")
     args = ap.parse_args()
@@ -318,7 +310,6 @@ def main():
                     arch, shape, mesh_kind, quantized_kv=args.quantized_kv,
                     sqrt_unit=args.sqrt_unit, microbatches=args.microbatches,
                     seq_parallel=args.seq_parallel, smoke=args.smoke,
-                    attribute_top=args.attribute,
                     extra_overrides={"remat": args.remat} if args.remat else None,
                 )
             except Exception as e:  # noqa: BLE001 — record the failure and move on

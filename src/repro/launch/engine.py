@@ -128,7 +128,7 @@ from repro.launch.journal import (
     replay_plan,
     replay_unit_levels,
 )
-from repro.launch.telemetry import Telemetry
+from repro.launch.telemetry import HostSpans, Telemetry
 from repro.models import lm
 from repro.models.config import ModelConfig
 
@@ -142,6 +142,7 @@ __all__ = [
     "solo_generate",
     "STATUSES",
     "SHED_POLICIES",
+    "HOST_SPANS",
 ]
 
 # Completion.status values, in degradation order (docs/robustness.md):
@@ -162,6 +163,22 @@ STATUSES = ("ok", "degraded", "evicted", "failed", "rejected")
 #                           SLO (smallest deadline slack right now);
 #                           deadline-free requests shed newest-first
 SHED_POLICIES = ("reject-new", "evict-latest-deadline", "shed-by-slo")
+
+# Host spans of Engine.run (launch/telemetry.py HostSpans), each reported as
+# ``stats["host_<name>_s"]``, its own seconds with nested spans taken out,
+# so together they cover the run once (docs/serving.md, "Taking a profile"):
+#   run             — the loop's own work: due arrivals, evictions, shedding
+#   admit           — one admission: validation and the prefill dispatch
+#   decode_dispatch — the decode chunk's jitted call, until it returns
+#   decode_sync     — waiting for the chunk and its one device-to-host transfer
+#   bookkeeping     — emission, finish and SLO updates after the sync
+#   journal         — journal writes in the loop
+#   telemetry       — building and writing the chunk's telemetry record
+#   snapshot        — autosaves
+#   wait_arrival    — sleeping with an idle pool until the next arrival
+#   gc              — Python garbage collection, whichever span it interrupted
+HOST_SPANS = ("run", "admit", "decode_dispatch", "decode_sync", "bookkeeping",
+              "journal", "telemetry", "snapshot", "wait_arrival", "gc")
 
 # snapshot meta-blob layout version (bumped on incompatible change)
 _SNAPSHOT_FORMAT = 1
@@ -314,6 +331,12 @@ class Completion:
     draft-and-verify steps the request's slot ran while it held it and
     ``spec_accepted`` the drafts those steps accepted;
     :attr:`accepted_per_step` is their ratio.
+
+    ``first_token_s`` is the chunk boundary (the decode chunk's host sync)
+    that delivered the request's first token, on the same clock as
+    ``arrival_s``; -1.0 for a request with no token.  A request restored by
+    :meth:`Engine.resume` with tokens already emitted reads 0.0, like its
+    ``admitted_s``.
     """
 
     uid: int
@@ -330,6 +353,7 @@ class Completion:
     unit_trips: tuple = ()
     spec_steps: int = 0
     spec_accepted: int = 0
+    first_token_s: float = -1.0
 
     @property
     def latency_s(self) -> float:
@@ -485,6 +509,7 @@ class Engine:
             raise ValueError("draft_model= without spec= has no effect; pass "
                              "spec=SpecConfig(draft='model')")
         self.spec = spec
+        self._spans = HostSpans()  # replaced by each run()
         self._draft_model = draft_model if (
             spec is not None and spec.draft == "model") else None
         self.params = params
@@ -599,6 +624,7 @@ class Engine:
             drafting with a model, prefills the draft model's own cache —
             still one dispatch per admission."""
 
+            # stable program name jit_admit_fn: device traces find admissions by it
             def admit_fn(p, cache, tok, pos, active, remaining, keys,
                          *rest):
                 i = 0
@@ -670,6 +696,7 @@ class Engine:
         if spec_on:
             spec_k = spec.k
 
+            # stable program name jit_decode_fn: device traces find chunks by it
             def decode_fn(p, c, tok, pos, act, rem, hist, *rest):
                 i = 0
                 kw = {}
@@ -698,6 +725,7 @@ class Engine:
             self.reset()
             return
 
+        # stable program name jit_decode_fn: device traces find chunks by it
         def decode_fn(p, c, tok, pos, act, rem, keys, *slo_args):
             with rules_ctx():
                 kw = {}
@@ -753,6 +781,7 @@ class Engine:
         self._owner: list[Optional[Request]] = [None] * b
         self._emitted: list[list[int]] = [[] for _ in range(b)]
         self._admitted_s = [0.0] * b
+        self._first_s = [-1.0] * b
         self._trips = [0] * b
         self._queue: deque = deque()      # due tickets waiting for a slot
         self._arrivals: deque = deque()   # accepted tickets not yet due
@@ -1050,6 +1079,7 @@ class Engine:
             self._owner[slot] = t.req
             self._emitted[slot] = [int(x) for x in rec.get("emitted", [])]
             self._admitted_s[slot] = 0.0  # clocks restart at resume
+            self._first_s[slot] = 0.0 if self._emitted[slot] else -1.0
             self._trips[slot] = t.trips
         self._queue = deque(_ticket_from_record(r) for r in meta["queue"])
         self._chunks_total = int(meta["chunks_total"])
@@ -1120,6 +1150,7 @@ class Engine:
             for slot in deactivate:
                 self._owner[slot] = None
                 self._emitted[slot] = []
+                self._first_s[slot] = -1.0
                 active[slot] = False
             if self.mesh is not None:
                 self._active = jax.device_put(active, self._pool_sh["vec"])
@@ -1234,40 +1265,42 @@ class Engine:
         return j
 
     def _admit(self, req: Request, slot: int, now: float, trips: int = 0):
-        self._validate(req)
-        level = 0 if self._ladder is None else int(self._unit_levels[slot])
-        prompt = jnp.asarray(req.prompt, jnp.int32)[None]
-        extra_in: tuple = ()
-        if self.spec is not None:
-            extra_in = (self._hist,)
-            if self._draft_model is not None:
-                extra_in = extra_in + (self._dcache,)
-        out = self._dispatch(
-            self._admit_jit_for(level),
-            self.params, self._cache, self._tok, self._pos, self._active,
-            self._remaining, self._keys, *extra_in, prompt,
-            np.asarray([slot], np.int32),
-            np.asarray([req.max_new_tokens], np.int32),
-            # sampling stream keyed by uid, not by slot
-            np.asarray([req.uid & 0x7FFFFFFF], np.int32),
-        )
-        (self._cache, self._tok, self._pos, self._active, self._remaining,
-         self._keys) = out[:6]
-        if self.spec is not None:
-            self._hist = out[6]
-            if self._draft_model is not None:
-                self._dcache = out[7]
-            self._slot_spec_steps[slot] = 0
-            self._slot_spec_acc[slot] = 0
-        self._owner[slot] = req
-        self._emitted[slot] = []
-        self._admitted_s[slot] = now
-        self._trips[slot] = trips
-        # request-scoped SLO state resets with the new occupant; the rung
-        # itself (and its divergence count / streak) is slot-scoped
-        self._slot_canary_checks[slot] = 0
-        self._slot_canary_div[slot] = 0
-        self._slot_events[slot] = []
+        with self._spans.span("admit", len(req.prompt)):
+            self._validate(req)
+            level = 0 if self._ladder is None else int(self._unit_levels[slot])
+            prompt = jnp.asarray(req.prompt, jnp.int32)[None]
+            extra_in: tuple = ()
+            if self.spec is not None:
+                extra_in = (self._hist,)
+                if self._draft_model is not None:
+                    extra_in = extra_in + (self._dcache,)
+            out = self._dispatch(
+                self._admit_jit_for(level),
+                self.params, self._cache, self._tok, self._pos, self._active,
+                self._remaining, self._keys, *extra_in, prompt,
+                np.asarray([slot], np.int32),
+                np.asarray([req.max_new_tokens], np.int32),
+                # sampling stream keyed by uid, not by slot
+                np.asarray([req.uid & 0x7FFFFFFF], np.int32),
+            )
+            (self._cache, self._tok, self._pos, self._active, self._remaining,
+             self._keys) = out[:6]
+            if self.spec is not None:
+                self._hist = out[6]
+                if self._draft_model is not None:
+                    self._dcache = out[7]
+                self._slot_spec_steps[slot] = 0
+                self._slot_spec_acc[slot] = 0
+            self._owner[slot] = req
+            self._emitted[slot] = []
+            self._admitted_s[slot] = now
+            self._first_s[slot] = -1.0
+            self._trips[slot] = trips
+            # request-scoped SLO state resets with the new occupant; the rung
+            # itself (and its divergence count / streak) is slot-scoped
+            self._slot_canary_checks[slot] = 0
+            self._slot_canary_div[slot] = 0
+            self._slot_events[slot] = []
 
     def _decode_chunk(self):
         if self.spec is not None:
@@ -1282,7 +1315,8 @@ class Engine:
                 np.asarray(self._unit_levels, np.int32),
                 np.int32(self._chunks_total * self.chunk),
             )
-        out = self._dispatch(self._decode_j, *args)
+        with self._spans.span("decode_dispatch"):
+            out = self._dispatch(self._decode_j, *args)
         (toks, emitted, self._tok, self._pos, self._active,
          self._remaining, self._cache) = out[:7]
         i = 7
@@ -1301,8 +1335,9 @@ class Engine:
         # the health signals and the canary gauges come back together
         # (separate np.asarray round-trips measurably dominate the
         # smoke-scale serve loop)
-        return jax.device_get((toks, emitted, self._active, bad, mx,
-                               cc, cd, cmr, crs))
+        with self._spans.span("decode_sync"):
+            return jax.device_get((toks, emitted, self._active, bad, mx,
+                                   cc, cd, cmr, crs))
 
     def _decode_chunk_spec(self):
         """The speculative twin of :meth:`_decode_chunk`: one jitted
@@ -1319,7 +1354,8 @@ class Engine:
         if self.slo is not None:
             args += [np.asarray(self._unit_levels, np.int32),
                      np.int32(self._chunks_total * self.chunk)]
-        out = self._dispatch(self._decode_j, *args)
+        with self._spans.span("decode_dispatch"):
+            out = self._dispatch(self._decode_j, *args)
         (toks, emitted, self._tok, self._pos, self._active,
          self._remaining, self._cache, self._hist) = out[:8]
         accepted, steps = out[8], out[9]
@@ -1338,8 +1374,9 @@ class Engine:
         else:
             cc = cd = np.zeros((self.num_slots,), np.int32)
             cmr = crs = np.zeros((self.num_slots,), np.float32)
-        got = jax.device_get((toks, emitted, self._active, bad, mx,
-                              cc, cd, cmr, crs, accepted, steps))
+        with self._spans.span("decode_sync"):
+            got = jax.device_get((toks, emitted, self._active, bad, mx,
+                                  cc, cd, cmr, crs, accepted, steps))
         acc_h, steps_h = got[9], got[10]
         self._slot_spec_acc += acc_h
         self._slot_spec_steps += steps_h
@@ -1390,7 +1427,8 @@ class Engine:
                     }
                     self._slot_events[slot].append(event)
                     if self._journal is not None:
-                        self._journal.demoted(slot, uid, level, ladder[level])
+                        with self._spans.span("journal"):
+                            self._journal.demoted(slot, uid, level, ladder[level])
             elif dv:
                 # divergent but within budget: hysteresis restarts anyway
                 self._clean_streak[slot] = 0
@@ -1410,7 +1448,8 @@ class Engine:
                     }
                     self._slot_events[slot].append(event)
                     if self._journal is not None:
-                        self._journal.promoted(slot, uid, level, ladder[level])
+                        with self._spans.span("journal"):
+                            self._journal.promoted(slot, uid, level, ladder[level])
 
     def _exact_fallback(self, req: Request):
         """The bottom rung of the degradation ladder: serve one request solo
@@ -1497,6 +1536,15 @@ class Engine:
         decode-chunk boundary — no draining, no terminal records for
         in-flight work — exactly what SIGKILL leaves behind
         (tests/launch/test_engine_snapshot.py, tools/kill_resume_smoke.py).
+
+        Host time: the run is the span ``engine.run``, whose start is the
+        clock's zero (``arrival_s``, ``admitted_s``, ``first_token_s`` and
+        telemetry ``t`` are seconds after it).  ``self.stats`` carries
+        ``host_<span>_s`` for each of :data:`HOST_SPANS`, and
+        ``longest_turn_s`` / ``longest_turn_chunk``: the longest host turn
+        between a chunk's sync and the next chunk's dispatch with work live,
+        and the telemetry ``chunk`` whose boundary began it (0.0 and -1.0
+        when no such turn ran).
         """
         requests = list(requests)
         for req in requests:
@@ -1525,195 +1573,223 @@ class Engine:
             "demotions": 0,
             "promotions": 0,
         }
-        t0 = time.perf_counter()
-        decode_chunks = 0
-        if self.spec is not None:
-            spec_acc0 = self._spec_acc_total
-            spec_steps0 = self._spec_steps_total
-        peak_queue_depth = len(queue)
-        queue_depth_sum = 0
-        queue_depth_samples = 0
-        telemetry_tokens = 0
-        expired = False
-        killed = False
+        spans = self._spans = HostSpans()
+        with spans.span("run") as t0, spans.collect_gc():
+            decode_chunks = 0
+            if self.spec is not None:
+                spec_acc0 = self._spec_acc_total
+                spec_steps0 = self._spec_steps_total
+            peak_queue_depth = len(queue)
+            queue_depth_sum = 0
+            queue_depth_samples = 0
+            telemetry_tokens = 0
+            expired = False
+            killed = False
+            # a host turn: from one chunk's sync to the next chunk's dispatch,
+            # while work is live (a turn that idles the pool is not counted)
+            turn_from = None
+            longest_turn, longest_turn_chunk = 0.0, -1
 
-        def finish(req, tokens, status, now, admitted_s, trips=0, slot=None):
-            audit = {}
-            if slot is not None and self._ladder is not None:
-                audit = dict(
-                    unit_final=self._ladder[int(self._unit_levels[slot])],
-                    canary_checks=int(self._slot_canary_checks[slot]),
-                    canary_divergences=int(self._slot_canary_div[slot]),
-                    unit_trips=tuple(self._slot_events[slot]),
+            def finish(req, tokens, status, now, admitted_s, trips=0, slot=None):
+                first_s = -1.0
+                if slot is not None and len(tokens):
+                    first_s = self._first_s[slot]
+                audit = {}
+                if slot is not None and self._ladder is not None:
+                    audit = dict(
+                        unit_final=self._ladder[int(self._unit_levels[slot])],
+                        canary_checks=int(self._slot_canary_checks[slot]),
+                        canary_divergences=int(self._slot_canary_div[slot]),
+                        unit_trips=tuple(self._slot_events[slot]),
+                    )
+                if slot is not None and self.spec is not None:
+                    audit.update(
+                        spec_steps=int(self._slot_spec_steps[slot]),
+                        spec_accepted=int(self._slot_spec_acc[slot]),
+                    )
+                done[req.uid] = Completion(
+                    uid=req.uid,
+                    prompt_len=len(req.prompt),
+                    tokens=np.asarray(tokens, np.int32),
+                    arrival_s=req.arrival_s,
+                    admitted_s=admitted_s,
+                    finished_s=now,
+                    status=status,
+                    trips=trips,
+                    first_token_s=first_s,
+                    **audit,
                 )
-            if slot is not None and self.spec is not None:
-                audit.update(
-                    spec_steps=int(self._slot_spec_steps[slot]),
-                    spec_accepted=int(self._slot_spec_acc[slot]),
-                )
-            done[req.uid] = Completion(
-                uid=req.uid,
-                prompt_len=len(req.prompt),
-                tokens=np.asarray(tokens, np.int32),
-                arrival_s=req.arrival_s,
-                admitted_s=admitted_s,
-                finished_s=now,
-                status=status,
-                trips=trips,
-                **audit,
-            )
-            if self._journal is not None:
-                self._journal.finished(req.uid, status, done[req.uid].tokens)
+                if self._journal is not None:
+                    with spans.span("journal"):
+                        self._journal.finished(req.uid, status, done[req.uid].tokens)
 
-        def overdue(req, now):
-            return req.deadline_s is not None and now > req.arrival_s + req.deadline_s
+            def overdue(req, now):
+                return req.deadline_s is not None and now > req.arrival_s + req.deadline_s
 
-        while queue or arrivals or any(o is not None for o in self._owner):
-            now = time.perf_counter() - t0
-            if now > deadline_s:
-                expired = True
-                break
-            if max_chunks is not None and decode_chunks >= max_chunks:
-                killed = True  # chaos hook: die at the chunk boundary
-                break
-            # accepted arrivals come due; the bound is enforced below, after
-            # free slots have drained the queue
-            while arrivals and arrivals[0].req.arrival_s <= now:
-                queue.append(arrivals.popleft())
-            # evict overdue queued requests before they can take a slot
-            if any(overdue(t.req, now) for t in queue):
-                kept = deque()
-                for t in queue:
-                    if overdue(t.req, now):
-                        counters["deadline_evictions"] += 1
-                        finish(t.req, [], "evicted", now, -1.0, t.trips)
-                    else:
-                        kept.append(t)
-                queue.clear()
-                queue.extend(kept)
-            # admit queued arrivals into free slots
-            for slot in range(self.num_slots):
-                if self._owner[slot] is None and queue:
-                    t = queue.popleft()
-                    self._admit(t.req, slot, now, trips=t.trips)
-                    if self._journal is not None:
-                        self._journal.admitted(t.req.uid, slot)
-            # overload admission control: requests that could not get a slot
-            # wait in a BOUNDED queue; beyond the bound the shed policy picks
-            # who is turned away (status "rejected")
-            while self.max_queue is not None and len(queue) > self.max_queue:
-                victim = self._shed_victim(now)
-                queue.remove(victim)
-                counters["shed_rejections"] += 1
-                finish(victim.req, [], "rejected", now, -1.0, victim.trips)
-            depth = len(queue)
-            peak_queue_depth = max(peak_queue_depth, depth)
-            queue_depth_sum += depth
-            queue_depth_samples += 1
-            if not any(o is not None for o in self._owner):
-                # pool idle: sleep until the next arrival
-                if arrivals:
-                    time.sleep(max(0.0, arrivals[0].req.arrival_s - now))
-                continue
-            toks, emitted, active, bad, mx, cc, cd, cmr, _crs = (
-                self._decode_chunk()
-            )
-            decode_chunks += 1
-            self._chunks_total += 1
-            now = time.perf_counter() - t0
-            if self.slo is not None and self._canary_stride:
-                # ladder bookkeeping first, so requests finishing this chunk
-                # carry their final rung + canary trail in the Completion
-                self._slo_update(cc, cd, cmr, counters)
-            for slot in range(self.num_slots):
-                req = self._owner[slot]
-                if req is None:
+            while queue or arrivals or any(o is not None for o in self._owner):
+                now = time.perf_counter() - t0
+                if now > deadline_s:
+                    expired = True
+                    break
+                if max_chunks is not None and decode_chunks >= max_chunks:
+                    killed = True  # chaos hook: die at the chunk boundary
+                    break
+                # accepted arrivals come due; the bound is enforced below, after
+                # free slots have drained the queue
+                while arrivals and arrivals[0].req.arrival_s <= now:
+                    queue.append(arrivals.popleft())
+                # evict overdue queued requests before they can take a slot
+                if any(overdue(t.req, now) for t in queue):
+                    kept = deque()
+                    for t in queue:
+                        if overdue(t.req, now):
+                            counters["deadline_evictions"] += 1
+                            finish(t.req, [], "evicted", now, -1.0, t.trips)
+                        else:
+                            kept.append(t)
+                    queue.clear()
+                    queue.extend(kept)
+                # admit queued arrivals into free slots
+                for slot in range(self.num_slots):
+                    if self._owner[slot] is None and queue:
+                        t = queue.popleft()
+                        self._admit(t.req, slot, now, trips=t.trips)
+                        if self._journal is not None:
+                            with spans.span("journal"):
+                                self._journal.admitted(t.req.uid, slot)
+                # overload admission control: requests that could not get a slot
+                # wait in a BOUNDED queue; beyond the bound the shed policy picks
+                # who is turned away (status "rejected")
+                while self.max_queue is not None and len(queue) > self.max_queue:
+                    victim = self._shed_victim(now)
+                    queue.remove(victim)
+                    counters["shed_rejections"] += 1
+                    finish(victim.req, [], "rejected", now, -1.0, victim.trips)
+                depth = len(queue)
+                peak_queue_depth = max(peak_queue_depth, depth)
+                queue_depth_sum += depth
+                queue_depth_samples += 1
+                if not any(o is not None for o in self._owner):
+                    # pool idle: sleep until the next arrival
+                    turn_from = None
+                    if arrivals:
+                        with spans.span("wait_arrival"):
+                            time.sleep(max(0.0, arrivals[0].req.arrival_s - now))
                     continue
-                # NaN mx compares False, but `bad` has latched in that case
-                tripped = self.detectors and (
-                    bool(bad[slot]) or float(mx[slot]) > self.logit_sentinel
+                if turn_from is not None:
+                    turn = time.perf_counter() - turn_from
+                    if turn > longest_turn:
+                        longest_turn, longest_turn_chunk = turn, self._chunks_total
+                toks, emitted, active, bad, mx, cc, cd, cmr, _crs = (
+                    self._decode_chunk()
                 )
-                if tripped:
-                    # quarantine: drop the slot (its device row decays
-                    # harmlessly — row isolation + budget exhaustion) and
-                    # discard every emission; the retry starts clean
-                    counters["faults_detected"] += 1
-                    trips = self._trips[slot] + 1
-                    self._owner[slot] = None
-                    if trips <= self.quarantine_retries:
-                        counters["quarantine_retries"] += 1
-                        queue.appendleft(_Ticket(req, trips))
-                    else:
-                        counters["exact_fallbacks"] += 1
-                        tokens, healthy = self._exact_fallback(req)
-                        now = time.perf_counter() - t0
-                        finish(req, tokens, "degraded" if healthy else "failed",
-                               now, self._admitted_s[slot], trips, slot=slot)
-                    continue
-                self._emitted[slot].extend(toks[slot][emitted[slot]].tolist())
-                if not active[slot]:  # finished: free the slot for reuse
-                    finish(req, self._emitted[slot], "ok", now,
-                           self._admitted_s[slot], self._trips[slot], slot=slot)
-                    self._owner[slot] = None
-                elif overdue(req, now):  # per-request deadline: partial out
+                turn_from = time.perf_counter()
+                decode_chunks += 1
+                self._chunks_total += 1
+                now = chunk_t = turn_from - t0
+                with spans.span("bookkeeping"):
+                    if self.slo is not None and self._canary_stride:
+                        # ladder bookkeeping first, so requests finishing this chunk
+                        # carry their final rung + canary trail in the Completion
+                        self._slo_update(cc, cd, cmr, counters)
+                    for slot in range(self.num_slots):
+                        req = self._owner[slot]
+                        if req is None:
+                            continue
+                        # NaN mx compares False, but `bad` has latched in that case
+                        tripped = self.detectors and (
+                            bool(bad[slot]) or float(mx[slot]) > self.logit_sentinel
+                        )
+                        if tripped:
+                            # quarantine: drop the slot (its device row decays
+                            # harmlessly — row isolation + budget exhaustion) and
+                            # discard every emission; the retry starts clean
+                            counters["faults_detected"] += 1
+                            trips = self._trips[slot] + 1
+                            self._owner[slot] = None
+                            if trips <= self.quarantine_retries:
+                                counters["quarantine_retries"] += 1
+                                queue.appendleft(_Ticket(req, trips))
+                            else:
+                                counters["exact_fallbacks"] += 1
+                                tokens, healthy = self._exact_fallback(req)
+                                now = time.perf_counter() - t0
+                                # the fallback delivers all its tokens at once
+                                self._first_s[slot] = now
+                                finish(req, tokens, "degraded" if healthy else "failed",
+                                       now, self._admitted_s[slot], trips, slot=slot)
+                            continue
+                        new = toks[slot][emitted[slot]].tolist()
+                        if new and not self._emitted[slot]:
+                            self._first_s[slot] = chunk_t
+                        self._emitted[slot].extend(new)
+                        if not active[slot]:  # finished: free the slot for reuse
+                            finish(req, self._emitted[slot], "ok", now,
+                                   self._admitted_s[slot], self._trips[slot], slot=slot)
+                            self._owner[slot] = None
+                        elif overdue(req, now):  # per-request deadline: partial out
+                            counters["deadline_evictions"] += 1
+                            finish(req, self._emitted[slot], "evicted", now,
+                                   self._admitted_s[slot], self._trips[slot], slot=slot)
+                            self._owner[slot] = None
+                if self._journal is not None:
+                    live = [
+                        (o.uid, len(self._emitted[s]))
+                        for s, o in enumerate(self._owner)
+                        if o is not None
+                    ]
+                    if live:
+                        with spans.span("journal"):
+                            self._journal.progress(live)
+                if self._telemetry is not None:
+                    with spans.span("telemetry"):
+                        n_active = sum(o is not None for o in self._owner)
+                        if self._ladder is not None:
+                            hist: dict = {}
+                            for lv in self._unit_levels:
+                                name = self._ladder[int(lv)]
+                                hist[name] = hist.get(name, 0) + 1
+                        else:
+                            hist = {self.cfg.sqrt_unit: self.num_slots}
+                        chunk_tokens = int(np.sum(emitted))
+                        telemetry_tokens += chunk_tokens
+                        self._telemetry.emit({
+                            "kind": "chunk",
+                            "t": now,
+                            "chunk": int(self._chunks_total),
+                            "active_slots": n_active,
+                            "slot_occupancy": n_active / self.num_slots,
+                            "queue_depth": depth,
+                            "tokens": chunk_tokens,
+                            "tok_s": telemetry_tokens / max(now, 1e-9),
+                            "canary_checks": int(np.sum(cc)),
+                            "canary_divergences": int(np.sum(cd)),
+                            "canary_max_rel": float(np.max(cmr)) if len(cmr) else 0.0,
+                            "unit_levels": hist,
+                        })
+                # autosave at the chunk boundary, after the host bookkeeping
+                # above — the durable cut the kill-and-resume chaos suite
+                # proves exactly-once recovery against
+                if (self.snapshot_every_chunks is not None
+                        and decode_chunks % self.snapshot_every_chunks == 0):
+                    with spans.span("snapshot"):
+                        self.snapshot()
+            if expired:
+                now = time.perf_counter() - t0
+                for slot in range(self.num_slots):
+                    req = self._owner[slot]
+                    if req is None:
+                        continue
                     counters["deadline_evictions"] += 1
                     finish(req, self._emitted[slot], "evicted", now,
                            self._admitted_s[slot], self._trips[slot], slot=slot)
                     self._owner[slot] = None
-            if self._journal is not None:
-                live = [
-                    (o.uid, len(self._emitted[s]))
-                    for s, o in enumerate(self._owner)
-                    if o is not None
-                ]
-                if live:
-                    self._journal.progress(live)
-            if self._telemetry is not None:
-                n_active = sum(o is not None for o in self._owner)
-                if self._ladder is not None:
-                    hist: dict = {}
-                    for lv in self._unit_levels:
-                        name = self._ladder[int(lv)]
-                        hist[name] = hist.get(name, 0) + 1
-                else:
-                    hist = {self.cfg.sqrt_unit: self.num_slots}
-                chunk_tokens = int(np.sum(emitted))
-                telemetry_tokens += chunk_tokens
-                self._telemetry.emit({
-                    "kind": "chunk",
-                    "t": now,
-                    "chunk": int(self._chunks_total),
-                    "active_slots": n_active,
-                    "slot_occupancy": n_active / self.num_slots,
-                    "queue_depth": depth,
-                    "tokens": chunk_tokens,
-                    "tok_s": telemetry_tokens / max(now, 1e-9),
-                    "canary_checks": int(np.sum(cc)),
-                    "canary_divergences": int(np.sum(cd)),
-                    "canary_max_rel": float(np.max(cmr)) if len(cmr) else 0.0,
-                    "unit_levels": hist,
-                })
-            # autosave at the chunk boundary, after the host bookkeeping
-            # above — the durable cut the kill-and-resume chaos suite
-            # proves exactly-once recovery against
-            if (self.snapshot_every_chunks is not None
-                    and decode_chunks % self.snapshot_every_chunks == 0):
-                self.snapshot()
-        if expired:
-            now = time.perf_counter() - t0
-            for slot in range(self.num_slots):
-                req = self._owner[slot]
-                if req is None:
-                    continue
-                counters["deadline_evictions"] += 1
-                finish(req, self._emitted[slot], "evicted", now,
-                       self._admitted_s[slot], self._trips[slot], slot=slot)
-                self._owner[slot] = None
-            for t in list(queue) + list(arrivals):
-                counters["deadline_evictions"] += 1
-                finish(t.req, [], "evicted", now, -1.0, t.trips)
-            queue.clear()
-            arrivals.clear()
+                for t in list(queue) + list(arrivals):
+                    counters["deadline_evictions"] += 1
+                    finish(t.req, [], "evicted", now, -1.0, t.trips)
+                queue.clear()
+                arrivals.clear()
+        # after the run span closes, so the host_*_s stats add up to at most it
         makespan = time.perf_counter() - t0
         total_tokens = sum(len(c.tokens) for c in done.values())
         by_status = {s: 0 for s in STATUSES}
@@ -1740,6 +1816,9 @@ class Engine:
                           else str(self._telemetry.path)),
             **counters,
             **{f"n_{s}": by_status[s] for s in STATUSES},
+            **{f"host_{k}_s": spans.seconds.get(k, 0.0) for k in HOST_SPANS},
+            "longest_turn_s": longest_turn,
+            "longest_turn_chunk": float(longest_turn_chunk),
         }
         if self.spec is not None:
             acc = self._spec_acc_total - spec_acc0

@@ -23,13 +23,23 @@ full field table):
 
 Unknown fields must be tolerated by readers (same forward-compat contract
 as the journal).  ``read_telemetry`` skips a torn final line.
+
+:class:`HostSpans` accounts one serve loop's host time by what the host was
+doing, and names each part in a profiler trace (docs/serving.md, "Taking a
+profile").
 """
 from __future__ import annotations
 
+import contextlib
+import gc
 import json
+import time
+from collections import defaultdict
 from pathlib import Path
 
-__all__ = ["Telemetry", "read_telemetry"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Telemetry", "HostSpans", "read_telemetry"]
 
 
 class Telemetry:
@@ -59,6 +69,58 @@ class Telemetry:
     def close(self) -> None:
         if self._f is not None and not self._f.closed:
             self._f.close()
+
+
+class HostSpans:
+    """Host time of one serve loop, by span.
+
+    ``span(name, tag)`` opens ``jax.profiler.TraceAnnotation("engine.<name>")``
+    (``"engine.<name>#<tag>"`` with a tag), which costs next to nothing
+    unless the profiler is recording, and adds the span's own
+    ``perf_counter`` seconds to ``seconds[name]``: its length less that of
+    the spans nested in it.  While :meth:`collect_gc` is active, Python's
+    garbage collections count under ``"gc"`` and come off the span they
+    interrupted.  So every second inside the outermost span is counted
+    exactly once, and ``seconds`` adds up to that span's length.
+    """
+
+    def __init__(self):
+        self.seconds: dict = defaultdict(float)
+        self._open: list = []  # [start, seconds of nested spans] per open span
+        self._gc_start = None
+
+    @contextlib.contextmanager
+    def span(self, name: str, tag=None):
+        """Yields the span's start on the ``perf_counter`` clock."""
+        with TraceAnnotation(f"engine.{name}" if tag is None else f"engine.{name}#{tag}"):
+            frame = [time.perf_counter(), 0.0]
+            self._open.append(frame)
+            try:
+                yield frame[0]
+            finally:
+                self._open.pop()
+                d = time.perf_counter() - frame[0]
+                self.seconds[name] += d - frame[1]
+                if self._open:
+                    self._open[-1][1] += d
+
+    def _on_gc(self, phase, _info):
+        if phase == "start":
+            self._gc_start = time.perf_counter()
+        elif self._gc_start is not None:
+            d = time.perf_counter() - self._gc_start
+            self._gc_start = None
+            self.seconds["gc"] += d
+            if self._open:
+                self._open[-1][1] += d
+
+    @contextlib.contextmanager
+    def collect_gc(self):
+        gc.callbacks.append(self._on_gc)
+        try:
+            yield
+        finally:
+            gc.callbacks.remove(self._on_gc)
 
 
 def read_telemetry(path) -> list[dict]:

@@ -420,52 +420,16 @@ def attention_prefill(p, cfg, x, cache, positions, *, window: Optional[int] = No
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype)), cache
 
 
-def attention_decode(p, cfg, x, cache, pos, *, window: Optional[int] = None,
-                     layer_idx=None, kernel: Optional[str] = None,
-                     norm_levels=None):
-    """Single-token decode. x: (b, 1, d); cache holds ``cache_len`` slots.
-
-    ``pos`` is either a scalar (lock-step batch: every row at the same
-    position) or a (b,) vector (slot-scheduled serving: each batch row is an
-    independent request with its own position counter — RoPE, the ring-buffer
-    write index and the validity mask all follow per row).
-
-    For sliding-window layers the cache is a ring buffer of size ``window``.
-    With ``layer_idx``, cache tensors carry a leading stacked-layers axis and
-    are updated in place (see _cache_update).  Returns (out, new_cache).
-
-    ``kernel`` routes the scored-attention block (defaults to
-    ``cfg.decode_kernel``): None keeps the inline XLA path; "fused" runs the
-    Pallas decode-attention kernel via the dispatch layer; "reference" runs
-    the kernel's pure-jnp oracle (same math, useful for bisecting).  The
-    projections, cache write and wo projection are identical on every route.
-
-    ``norm_levels`` (accuracy-SLO serving, (b,) int32): per-slot ladder rung
-    for the qk-norm rsqrt when ``cfg.sqrt_ladder`` is set; None keeps the
-    single-datapath path bit-for-bit.
-    """
-    b, s, d = x.shape
-    assert s == 1
+def _decode_attend(cfg, x, q, k_new, v_new, cache, pos, *, window, layer_idx,
+                   kernel):
+    """The cached part of :func:`attention_decode`, between the q/k/v and
+    output projections: write the new token's K/V line, read the cache back
+    and attend over it.  Returns (per-head out (b, 1, h, hd), new cache)."""
+    b = x.shape[0]
     t_axis = 1 if layer_idx is None else 2
     cache_len = cache["k"].shape[t_axis]
     quantized = cache["k"].dtype == jnp.int8
-    pos = jnp.asarray(pos, jnp.int32)
     per_slot = pos.ndim == 1
-
-    # rope position of the new token: (1,) broadcasts over the batch in the
-    # scalar case; (b, 1) rotates each row at its own position
-    kv_pos_q = pos[:, None] if per_slot else jnp.asarray([0], jnp.int32) + pos
-    use_rope = cfg.pos == "rope"
-    q, k_new, v_new = _project_qkv(
-        p, cfg, x, x, kv_pos_q, kv_pos_q, use_rope=use_rope, norm_levels=norm_levels
-    )
-    # mesh serving (no-ops single-device): per serve_rules the token line each
-    # row writes is kv-head-sharded like the cache itself, so the per-slot
-    # ring write stays a shard-local scatter
-    q = constrain(q, ("batch", "seq", "heads", None))
-    k_new = constrain(k_new, ("batch", "seq", "kv_heads", None))
-    v_new = constrain(v_new, ("batch", "seq", "kv_heads", None))
-
     # ring-buffer slot; for full caches cache_len covers all positions so
     # this is just ``pos``
     slot = jnp.asarray(pos % cache_len, jnp.int32)
@@ -515,7 +479,6 @@ def attention_decode(p, cfg, x, cache, pos, *, window: Optional[int] = None,
             q[:, 0], k, v, pos_b, k_scale, v_scale,
             scale=cfg.d_head**-0.5, wrap=bool(window),
         )[:, None]
-        out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
         return out, cache
 
     # mask out unwritten slots: before the ring wraps only slots <= pos hold
@@ -538,6 +501,60 @@ def attention_decode(p, cfg, x, cache, pos, *, window: Optional[int] = None,
     out = _fold_masked_attention(
         q, k, v, mask, cfg.d_head**-0.5, k_scale, v_scale, x.dtype
     )
+    return out, cache
+
+
+def attention_decode(p, cfg, x, cache, pos, *, window: Optional[int] = None,
+                     layer_idx=None, kernel: Optional[str] = None,
+                     norm_levels=None):
+    """Single-token decode. x: (b, 1, d); cache holds ``cache_len`` slots.
+
+    ``pos`` is either a scalar (lock-step batch: every row at the same
+    position) or a (b,) vector (slot-scheduled serving: each batch row is an
+    independent request with its own position counter — RoPE, the ring-buffer
+    write index and the validity mask all follow per row).
+
+    For sliding-window layers the cache is a ring buffer of size ``window``.
+    With ``layer_idx``, cache tensors carry a leading stacked-layers axis and
+    are updated in place (see _cache_update).  Returns (out, new_cache).
+
+    ``kernel`` routes the scored-attention block (defaults to
+    ``cfg.decode_kernel``): None keeps the inline XLA path; "fused" runs the
+    Pallas decode-attention kernel via the dispatch layer; "reference" runs
+    the kernel's pure-jnp oracle (same math, useful for bisecting).  The
+    projections, cache write and wo projection are identical on every route.
+
+    ``norm_levels`` (accuracy-SLO serving, (b,) int32): per-slot ladder rung
+    for the qk-norm rsqrt when ``cfg.sqrt_ladder`` is set; None keeps the
+    single-datapath path bit-for-bit.
+
+    The cache write, the cache read and the scored attention run under
+    ``jax.named_scope("decode_attention")`` (metadata only); the q/k/v and
+    output projections are weight matmuls and stay outside it.
+    """
+    assert x.shape[1] == 1
+    pos = jnp.asarray(pos, jnp.int32)
+    per_slot = pos.ndim == 1
+
+    # rope position of the new token: (1,) broadcasts over the batch in the
+    # scalar case; (b, 1) rotates each row at its own position
+    kv_pos_q = pos[:, None] if per_slot else jnp.asarray([0], jnp.int32) + pos
+    use_rope = cfg.pos == "rope"
+    q, k_new, v_new = _project_qkv(
+        p, cfg, x, x, kv_pos_q, kv_pos_q, use_rope=use_rope, norm_levels=norm_levels
+    )
+    # mesh serving (no-ops single-device): per serve_rules the token line each
+    # row writes is kv-head-sharded like the cache itself, so the per-slot
+    # ring write stays a shard-local scatter
+    q = constrain(q, ("batch", "seq", "heads", None))
+    k_new = constrain(k_new, ("batch", "seq", "kv_heads", None))
+    v_new = constrain(v_new, ("batch", "seq", "kv_heads", None))
+
+    with jax.named_scope("decode_attention"):
+        out, cache = _decode_attend(
+            cfg, x, q, k_new, v_new, cache, pos, window=window,
+            layer_idx=layer_idx, kernel=kernel,
+        )
     out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
     return out, cache
 

@@ -5,9 +5,14 @@ integrated at its highest-traffic site (every layer of every architecture).
 routes through the E2AFS-R integer datapath (multiplier-free rsqrt), "exact"
 through ``jax.lax.rsqrt``.  The reduction is fp32 regardless of activation
 dtype; the rsqrt itself runs in the reduction dtype's bit format.
+
+Every norm runs under ``jax.named_scope("norm")``: metadata only, so the
+compiled code is unchanged and a device trace can tell the norm datapath's
+time apart (docs/serving.md, "Taking a profile").
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import get_unit, resolve_ladder
@@ -61,24 +66,27 @@ def rmsnorm(
             raise ValueError("fused rmsnorm has no fault-injection hook; use fused=False")
         from repro.kernels.rmsnorm.ops import rmsnorm as rmsnorm_kernel
 
-        return rmsnorm_kernel(x, scale.astype(jnp.float32), eps=eps)
+        with jax.named_scope("norm"):
+            return rmsnorm_kernel(x, scale.astype(jnp.float32), eps=eps)
     unit = get_unit(sqrt_unit, faults=faults)
-    dt = x.dtype
-    xf = x.astype(jnp.float32)
-    ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
-    inv = unit.rsqrt(ms + eps)
-    return (xf * inv).astype(dt) * (1.0 + scale.astype(dt))
+    with jax.named_scope("norm"):
+        dt = x.dtype
+        xf = x.astype(jnp.float32)
+        ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
+        inv = unit.rsqrt(ms + eps)
+        return (xf * inv).astype(dt) * (1.0 + scale.astype(dt))
 
 
 def rmsnorm_select(scale, x, levels, *, ladder, eps: float = 1e-6, faults=None):
     """Per-row ladder variant of :func:`rmsnorm` for accuracy-SLO decode:
     row ``i`` routes its rsqrt through ``ladder[levels[i]]``.  The mean-square
     reduction is computed once; only the (tiny) rsqrt runs per rung."""
-    dt = x.dtype
-    xf = x.astype(jnp.float32)
-    ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
-    inv = _select_inv(ms + eps, levels, ladder, faults, x.ndim)
-    return (xf * inv).astype(dt) * (1.0 + scale.astype(dt))
+    with jax.named_scope("norm"):
+        dt = x.dtype
+        xf = x.astype(jnp.float32)
+        ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
+        inv = _select_inv(ms + eps, levels, ladder, faults, x.ndim)
+        return (xf * inv).astype(dt) * (1.0 + scale.astype(dt))
 
 
 def layernorm_init(ini: DenseInit, name: str, d: int):
@@ -88,19 +96,21 @@ def layernorm_init(ini: DenseInit, name: str, d: int):
 
 def layernorm(scale, bias, x, *, sqrt_unit: str = "exact", eps: float = 1e-5, faults=None):
     unit = get_unit(sqrt_unit, faults=faults)
-    dt = x.dtype
-    xf = x.astype(jnp.float32)
-    mu = jnp.mean(xf, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(xf - mu), axis=-1, keepdims=True)
-    inv = unit.rsqrt(var + eps)
-    return ((xf - mu) * inv).astype(dt) * scale.astype(dt) + bias.astype(dt)
+    with jax.named_scope("norm"):
+        dt = x.dtype
+        xf = x.astype(jnp.float32)
+        mu = jnp.mean(xf, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(xf - mu), axis=-1, keepdims=True)
+        inv = unit.rsqrt(var + eps)
+        return ((xf - mu) * inv).astype(dt) * scale.astype(dt) + bias.astype(dt)
 
 
 def layernorm_select(scale, bias, x, levels, *, ladder, eps: float = 1e-5, faults=None):
     """Per-row ladder variant of :func:`layernorm` (see :func:`rmsnorm_select`)."""
-    dt = x.dtype
-    xf = x.astype(jnp.float32)
-    mu = jnp.mean(xf, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(xf - mu), axis=-1, keepdims=True)
-    inv = _select_inv(var + eps, levels, ladder, faults, x.ndim)
-    return ((xf - mu) * inv).astype(dt) * scale.astype(dt) + bias.astype(dt)
+    with jax.named_scope("norm"):
+        dt = x.dtype
+        xf = x.astype(jnp.float32)
+        mu = jnp.mean(xf, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(xf - mu), axis=-1, keepdims=True)
+        inv = _select_inv(var + eps, levels, ladder, faults, x.ndim)
+        return ((xf - mu) * inv).astype(dt) * scale.astype(dt) + bias.astype(dt)
