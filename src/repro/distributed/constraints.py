@@ -21,6 +21,7 @@ __all__ = [
     "constrain",
     "logical_to_spec",
     "current_rules",
+    "shard_count",
 ]
 
 _state = threading.local()
@@ -90,3 +91,19 @@ def constrain(x: jax.Array, axes: Sequence[Optional[str]]):
 
     spec = divisible_spec(logical_to_spec(axes, rules), x.shape, mesh)
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+
+
+def shard_count(axes: Sequence[Optional[str]], shape: Sequence[int]) -> int:
+    """How many devices :func:`constrain` would split a tensor of ``shape``
+    over along the logical ``axes``; 1 outside any rules scope."""
+    ctx = current_rules()
+    if ctx is None:
+        return 1
+    mesh, rules = ctx
+    from repro.distributed.sharding import divisible_spec  # avoid cycle at import
+
+    n = 1
+    for p in divisible_spec(logical_to_spec(axes, rules), shape, mesh):
+        for a in () if p is None else (p,) if isinstance(p, str) else p:
+            n *= mesh.shape[a]
+    return n

@@ -9,10 +9,14 @@ score/weight tensors through HBM between XLA ops.
 
 Bit-exactness contract: the in-kernel op sequence mirrors
 ``layers/attention.py:_fold_masked_attention`` term for term — the same
-einsum strings, the same fp32 casts, the same additive -2e38 mask, the same
-scale folding — so interpret-mode output is bit-identical to the inline XLA
-decode path and the engine's staggered-vs-solo parity suites hold with the
-kernel enabled (float32; bf16 tolerance documented in docs/kernels.md).
+grouped-query contractions (``_grouped_scores`` / ``_grouped_out``: each kv
+head against its g query heads, the cache never repeated to h heads), the
+same fp32 casts, the same additive -2e38 mask, the same scale folding — so
+interpret-mode output is bit-identical to the inline XLA decode path and
+the engine's staggered-vs-solo parity suites hold with the kernel enabled
+(float32; bf16 tolerance documented in docs/kernels.md).  The training and
+cross-attention paths repeat kv heads instead (``_expand_kv``), which keeps
+the head axis whole for the training meshes' sharding.
 
 The validity mask is built in-kernel from the per-row positions of the
 slot-pool contract (a ``(block_b, 1)`` int32 operand): slot ``t`` is live
@@ -27,36 +31,41 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-__all__ = ["decode_attention_kernel_call"]
+from repro.layers.attention import (
+    NEG_INF,
+    _gqa_out,
+    _gqa_scores,
+    _group_queries,
+    _grouped_out,
+    _grouped_scores,
+    _per_kv_line,
+)
 
-# matches layers/attention.py NEG_INF — the additive-mask contract
-NEG_INF = -2.0e38
+__all__ = ["decode_attention_kernel_call"]
 
 
 def _attend(q, k, v, pos, k_scale, v_scale, *, scale, wrap, out_dtype):
     """One tile of fused decode attention; q (bb, 1, h, hd), k/v
     (bb, t, kv, hd), pos (bb,), scales (bb, t, kv) or None."""
     bb, t, kv, hd = k.shape
-    g = q.shape[2] // kv
-    kx = k if g == 1 else jnp.repeat(k, g, axis=2)
-    scores = jnp.einsum("bshk,bthk->bhst", q, kx).astype(jnp.float32) * scale
+    grouped = q.shape[2] > kv  # no mesh inside a kernel: GQA always groups
+    if grouped:
+        scores = _grouped_scores(_group_queries(q, kv), k)
+    else:
+        scores = _gqa_scores(q, k)[:, :, None]
+    scores = scores.astype(jnp.float32) * scale  # (bb, n, g, 1, t)
     if k_scale is not None:
-        ks = jnp.moveaxis(k_scale, 1, 2)  # (bb, kv, t)
-        ks = ks if g == 1 else jnp.repeat(ks, g, axis=1)
-        scores = scores * ks[:, :, None, :]
+        scores = scores * _per_kv_line(k_scale, scores.shape[1])
     t_idx = jax.lax.broadcasted_iota(jnp.int32, (bb, t), 1)
     valid = t_idx <= pos[:, None]
     if wrap:
         valid = valid | (pos[:, None] >= t)
     mask = jnp.where(valid, 0.0, NEG_INF)  # (bb, t) additive, fp32
-    scores = scores + mask[:, None, None, :]
+    scores = scores + mask[:, None, None, None, :]
     w = jax.nn.softmax(scores, axis=-1).astype(out_dtype)
     if v_scale is not None:
-        vs = jnp.moveaxis(v_scale, 1, 2)
-        vs = vs if g == 1 else jnp.repeat(vs, g, axis=1)
-        w = w * vs[:, :, None, :].astype(w.dtype)
-    vx = v if g == 1 else jnp.repeat(v, g, axis=2)
-    return jnp.einsum("bhst,bthk->bshk", w, vx)
+        w = w * _per_kv_line(v_scale, w.shape[1]).astype(w.dtype)
+    return _grouped_out(w, v) if grouped else _gqa_out(w[:, :, 0], v)
 
 
 def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, *, scale, wrap):

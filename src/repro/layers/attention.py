@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from repro.distributed.constraints import constrain
+from repro.distributed.constraints import constrain, shard_count
 from repro.layers.norms import rmsnorm, rmsnorm_select
 from repro.layers.param import DenseInit, zeros
 from repro.layers.rope import apply_rope
@@ -99,12 +99,17 @@ def _softmax_scores(sc, out_dtype):
 
 
 def _expand_kv(k, h):
-    """Broadcast kv heads up to h query heads.  Deliberately NOT a reshape of
-    q into (kv, group): that splits the sharded head dim into factors the
-    mesh can't divide (e.g. 48 -> (4,12) on a 16-wide axis) and GSPMD then
-    REPLICATES the O(s^2) score tensors — measured 16x memory blowup on
-    starcoder2 prefill (§Perf prefill study).  The repeat keeps 'h' intact
-    (and fuses into the einsum on TPU)."""
+    """Broadcast kv heads up to h query heads, for the training, chunked and
+    cross-attention paths.  Deliberately NOT a reshape of q into (kv,
+    group) there: on training and prefill meshes that splits the sharded
+    head dim into factors the mesh can't divide (e.g. 48 -> (4,12) on a
+    16-wide axis) and GSPMD then REPLICATES the O(s^2) score tensors —
+    measured 16x memory blowup on starcoder2 prefill (§Perf prefill study).
+    The repeat keeps 'h' intact.  The serving block
+    (:func:`_fold_masked_attention`) takes the grouped contraction instead
+    (:func:`_grouped_scores`): there the repeat would copy the whole KV
+    cache g-fold in every layer of every decode step.  It keeps the repeat
+    only for MHA and on such meshes (:func:`_serve_grouped`)."""
     g = h // k.shape[2]
     return k if g == 1 else jnp.repeat(k, g, axis=2)
 
@@ -117,6 +122,57 @@ def _gqa_scores(q, k):
 def _gqa_out(weights, v):
     """weights: (b, h, s, t), v: (b,t,kv,k) -> (b,s,h,k)."""
     return jnp.einsum("bhst,bthk->bshk", weights, _expand_kv(v, weights.shape[1]))
+
+
+# query head j = n * g + i belongs to kv head n, as jnp.repeat(k, g, axis=2)
+# assigns it.  Under serve rules n takes the cache's kv-head sharding, so the
+# cache is read where it lies; where kv heads are unsharded, i takes the
+# query heads' sharding when it divides (else _serve_grouped keeps flat h)
+_GROUPED_Q = ("batch", "seq", "kv_heads", "heads", None)  # (b, s, kv, g, hd)
+_GROUPED_SCORES = ("batch", "kv_heads", "heads", "seq", None)  # (b, kv, g, s, t)
+
+
+def _serve_grouped(h, kv):
+    """Whether the serving block contracts grouped queries: for GQA, unless
+    the ambient mesh rules would split the grouped head axes (kv, g) over
+    fewer devices than the flat query-head axis.  That happens where
+    neither factor divides the model axis while h does — starcoder2-15b's
+    (4, 12) on a 16-wide axis — and GSPMD would then replicate the scores
+    on every model shard; the flat layout against the repeated cache
+    (:func:`_gqa_scores`) keeps them sharded there.  MHA (g == 1) has
+    nothing to group and keeps the flat einsum."""
+    g = h // kv
+    return g > 1 and (shard_count(("kv_heads", "heads"), (kv, g))
+                      >= shard_count(("heads",), (h,)))
+
+
+def _group_queries(q, kv):
+    """q: (b, s, h, hd) -> (b, s, kv, g, hd), query head j at (j // g, j % g)."""
+    b, s, h, hd = q.shape
+    return q.reshape(b, s, kv, h // kv, hd)
+
+
+def _grouped_scores(qg, k):
+    """qg: (b, s, kv, g, hd)  k: (b, t, kv, hd) -> scores (b, kv, g, s, t).
+    Each kv head is contracted against its own query group, so K is read
+    as it lies."""
+    return jnp.einsum("bsngk,btnk->bngst", qg, k)
+
+
+def _grouped_out(w, v):
+    """w: (b, kv, g, s, t)  v: (b, t, kv, hd) -> (b, s, h, hd)."""
+    b, kv, g, s, _ = w.shape
+    out = jnp.einsum("bngst,btnk->bsngk", w, v)
+    return out.reshape(b, s, kv * g, v.shape[-1])
+
+
+def _per_kv_line(scale, n):
+    """int8 cache scales (b, t, kv) -> (b, n, 1, 1, t) for scores laid out
+    as n head groups: folded per kv head (n == kv) or repeated to each
+    query head (n == h, the flat layout)."""
+    s = jnp.moveaxis(scale, 1, 2)
+    s = s if n == s.shape[1] else jnp.repeat(s, n // s.shape[1], axis=1)
+    return s[:, :, None, None, :]
 
 
 def attention_train(
@@ -343,22 +399,34 @@ def _fold_masked_attention(q, k, v, mask, scale, k_scale, v_scale, out_dtype):
     scores, int8 cache scales FOLDED into scores / weights (never a
     dequantized cache copy), additive fp32 mask, fp32 softmax.
 
+    Scores and the weighted sum are grouped-query contractions
+    (:func:`_grouped_scores`): each kv head meets its g query heads, and
+    the cache is never expanded to h heads.  In decode the expansion
+    (:func:`_expand_kv`, which the training paths keep) would be a g-fold
+    copy of the whole cache in every layer of every step.  MHA, and a mesh
+    whose model axis divides h but neither kv nor g
+    (:func:`_serve_grouped`), take the flat layout: scores (b, h, 1, sq, t)
+    against the repeated cache.
+
     q: (b, sq, h, hd); k/v: (b, t, kv, hd), int8 values pre-cast to
     ``out_dtype``; mask: (sq, t) additive, or (b, sq, t) when validity is
     per batch row (slot-scheduled decode); scales: (b, t, kv) or None.
     Returns (b, sq, h, hd) — the wo projection stays with the caller.
     """
-    g = q.shape[2] // k.shape[2]
-    scores = _gqa_scores(q, k).astype(jnp.float32) * scale  # (b, h, sq, t)
+    grouped = _serve_grouped(q.shape[2], k.shape[2])
+    if grouped:
+        qg = constrain(_group_queries(q, k.shape[2]), _GROUPED_Q)
+        scores = constrain(_grouped_scores(qg, k), _GROUPED_SCORES)
+    else:
+        scores = _gqa_scores(q, k)[:, :, None]
+    scores = scores.astype(jnp.float32) * scale  # (b, n, g, sq, t)
     if k_scale is not None:
-        ks = jnp.repeat(jnp.moveaxis(k_scale, 1, 2), g, axis=1)  # (b, h, t)
-        scores = scores * ks[:, :, None, :]
-    scores = scores + (mask[None, None] if mask.ndim == 2 else mask[:, None])
+        scores = scores * _per_kv_line(k_scale, scores.shape[1])
+    scores = scores + (mask if mask.ndim == 2 else mask[:, None, None])
     w = jax.nn.softmax(scores, axis=-1).astype(out_dtype)
     if v_scale is not None:
-        vs = jnp.repeat(jnp.moveaxis(v_scale, 1, 2), g, axis=1)
-        w = w * vs[:, :, None, :].astype(w.dtype)
-    return _gqa_out(w, v)
+        w = w * _per_kv_line(v_scale, w.shape[1]).astype(w.dtype)
+    return _grouped_out(w, v) if grouped else _gqa_out(w[:, :, 0], v)
 
 
 def attention_prefill(p, cfg, x, cache, positions, *, window: Optional[int] = None,

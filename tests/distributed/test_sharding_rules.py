@@ -89,3 +89,44 @@ class TestRuleTables:
         cfg = get_config("qwen3-4b")
         assert train_rules(cfg, self._mesh())["seq"] is None
         assert train_rules(cfg, self._mesh(), seq_parallel=True)["seq"] == "model"
+
+
+class TestServeAttentionLayout:
+    """The serving attention block groups queries per kv head wherever the
+    serve rules split (kv, g) over as many devices as the flat h."""
+
+    def _mesh(self, shape, axes):
+        dev = np.asarray([jax.devices()[0]] * int(np.prod(shape))).reshape(shape)
+        return Mesh(dev, axes)
+
+    def test_shard_count_outside_rules(self):
+        from repro.distributed.constraints import shard_count
+
+        assert shard_count(("heads",), (48,)) == 1
+
+    @pytest.mark.parametrize("name,shape,axes,grouped", [
+        ("qwen3-4b", (4, 4), ("data", "model"), True),  # kv 8 over 4
+        ("qwen3-4b", (16, 16), ("data", "model"), False),  # 8 x 4, h 32 over 16
+        ("starcoder2-15b", (16, 16), ("data", "model"), False),  # 4 x 12, h 48
+        ("deepseek-67b", (16, 16), ("data", "model"), False),  # 8 x 8, h 64
+        ("deepseek-67b", (16, 8, 2), ("data", "kv", "qg"), True),  # kv x qg mesh
+        ("gemma3-1b", (4, 4), ("data", "model"), True),  # 1 x 4 over 4
+    ])
+    def test_layout_under_serve_rules(self, name, shape, axes, grouped):
+        from repro.distributed.constraints import axis_rules, shard_count
+        from repro.layers.attention import _serve_grouped
+
+        cfg = get_config(name)
+        h, kv = cfg.n_heads, cfg.n_kv_heads
+        mesh = self._mesh(shape, axes)
+        with axis_rules(mesh, serve_rules(cfg, mesh)):
+            flat = shard_count(("heads",), (h,))
+            assert _serve_grouped(h, kv) is grouped
+            if grouped:  # the scores shard as far as the flat layout's
+                assert shard_count(("kv_heads", "heads"), (kv, h // kv)) == flat
+        assert _serve_grouped(h, kv)  # one device: every GQA config groups
+
+    def test_mha_stays_flat(self):
+        from repro.layers.attention import _serve_grouped
+
+        assert not _serve_grouped(32, 32)

@@ -75,14 +75,21 @@ class TestKernelParity:
         )
 
     def test_gqa_grouping_vs_mha(self):
-        """kv == h (no grouping) must agree with the same cache expanded
-        through the GQA repeat — the g==1 kernel branch."""
+        """kv == h (no grouping, the g==1 kernel branch) on the cache
+        expanded through the GQA repeat agrees with the grouped contraction
+        on the kv-head cache to fp32 rounding: the two contract the same
+        lines, in another order inside each dot product.  On the GQA input
+        the kernel still equals its oracle bit for bit."""
         q, k, v, pos, _, _ = _inputs()
         kx = jnp.repeat(k, H // KV, axis=2)
         vx = jnp.repeat(v, H // KV, axis=2)
         out_gqa = _run((q, k, v, pos))
         out_mha = _run((q, kx, vx, pos))
-        np.testing.assert_array_equal(np.asarray(out_gqa), np.asarray(out_mha))
+        np.testing.assert_allclose(
+            np.asarray(out_gqa), np.asarray(out_mha), rtol=1e-6, atol=1e-6
+        )
+        ref_gqa = ref_decode_attention(q, k, v, pos, scale=SCALE)
+        np.testing.assert_array_equal(np.asarray(out_gqa), np.asarray(ref_gqa))
 
     @pytest.mark.parametrize("block", [(1,), (2,), (4,), (8,), (16,)])
     def test_batch_tiling_invariant(self, block):
@@ -165,3 +172,87 @@ class TestAttentionDecodeRouting:
                                   kernel="flash")
         with pytest.raises(AssertionError):
             cfg.replace(decode_kernel="flash").validate()
+
+
+class TestGroupedContraction:
+    """The serving block contracts each kv head against its query group
+    instead of repeating the cache to h heads.  Query head ``j`` still sees
+    kv head ``j // g`` — what the repeat assigned — and MHA (g == 1) keeps
+    the plain einsum bit for bit.  On a mesh whose model axis divides h
+    but neither kv nor g, the block keeps the flat layout, so the scores
+    stay sharded (``test_tpu_compile.py``)."""
+
+    KV, T = 2, 16
+
+    def _inputs(self, g, sq, quantized):
+        b, h = 3, self.KV * g
+        ks = jax.random.split(jax.random.key(g * 10 + sq), 5)
+        q = jax.random.normal(ks[0], (b, sq, h, HD), jnp.float32)
+        k = jax.random.normal(ks[1], (b, self.T, self.KV, HD), jnp.float32)
+        v = jax.random.normal(ks[2], (b, self.T, self.KV, HD), jnp.float32)
+        k_scale = v_scale = None
+        if quantized:
+            k_scale = jnp.abs(jax.random.normal(ks[3], (b, self.T, self.KV))) * 0.1 + 0.01
+            v_scale = jnp.abs(jax.random.normal(ks[4], (b, self.T, self.KV))) * 0.1 + 0.01
+        # per-row validity, as slot-scheduled decode builds it
+        pos = jnp.asarray([0, 7, 15])[:, None] + jnp.arange(sq)[None] - (sq - 1)
+        mask = jnp.where(jnp.arange(self.T)[None, None] <= pos[:, :, None], 0.0,
+                         attn.NEG_INF)
+        return q, k, v, mask, k_scale, v_scale
+
+    @staticmethod
+    def _per_head(q, k, v, mask, k_scale, v_scale):
+        """The block's maths one query head at a time over k[:, :, j // g]."""
+        g = q.shape[2] // k.shape[2]
+        outs = []
+        for j in range(q.shape[2]):
+            n = j // g
+            sc = jnp.einsum("bsk,btk->bst", q[:, :, j], k[:, :, n]) * SCALE
+            if k_scale is not None:
+                sc = sc * k_scale[:, None, :, n]
+            w = jax.nn.softmax(sc + mask, axis=-1)
+            if v_scale is not None:
+                w = w * v_scale[:, None, :, n]
+            outs.append(jnp.einsum("bst,btk->bsk", w, v[:, :, n]))
+        return jnp.stack(outs, axis=2)
+
+    @pytest.mark.parametrize("quantized", [False, True])
+    @pytest.mark.parametrize("sq", [1, 5])
+    @pytest.mark.parametrize("g", [1, 4, 6])
+    def test_block_matches_per_head_loop(self, g, sq, quantized):
+        q, k, v, mask, k_scale, v_scale = self._inputs(g, sq, quantized)
+        out = attn._fold_masked_attention(q, k, v, mask, SCALE, k_scale, v_scale,
+                                          jnp.float32)
+        assert out.shape == q.shape
+        ref = self._per_head(q, k, v, mask, k_scale, v_scale)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=1e-6, atol=1e-6)
+
+    @pytest.mark.parametrize("sq", [1, 5])
+    @pytest.mark.parametrize("g", [1, 4, 6])
+    def test_helpers_against_repeat(self, g, sq):
+        """Against the repeat's ``_gqa_scores`` / ``_gqa_out``: MHA
+        (g == 1) takes them unchanged, bit for bit; grouped scores and
+        weighted sum agree within fp32 rounding.  (The grouped einsum at
+        g == 1 would not be bit-equal: it sums in another order.)"""
+        q, k, v, mask, _, _ = self._inputs(g, sq, False)
+        b, _, h, _ = q.shape
+        sc_rep = attn._gqa_scores(q, k)
+        if g == 1:
+            assert not attn._serve_grouped(h, self.KV)
+            out = attn._fold_masked_attention(q, k, v, mask, SCALE, None, None,
+                                              jnp.float32)
+            w_rep = jax.nn.softmax(sc_rep * SCALE + mask[:, None], axis=-1)
+            np.testing.assert_array_equal(np.asarray(out),
+                                          np.asarray(attn._gqa_out(w_rep, v)))
+            return
+        assert attn._serve_grouped(h, self.KV)
+        sc = attn._grouped_scores(attn._group_queries(q, self.KV), k)
+        assert sc.shape == (b, self.KV, g, sq, self.T)
+        w = jax.nn.softmax(sc, axis=-1)
+        out = attn._grouped_out(w, v)
+        out_rep = attn._gqa_out(w.reshape(b, h, sq, self.T), v)
+        np.testing.assert_allclose(np.asarray(sc.reshape(sc_rep.shape)),
+                                   np.asarray(sc_rep), rtol=1e-6, atol=1e-5)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(out_rep),
+                                   rtol=1e-6, atol=1e-6)

@@ -1,8 +1,9 @@
 """Compiles for a described TPU v5e, with no chip attached: the main path's
 Pallas kernels at real widths, at the tiles the roofline prior picks for
 that chip, and one qwen3-4b decode chunk at published widths, which must
-fit one chip's HBM.  Nothing runs; a compile the chip's compiler refuses
-fails here, at no chip time.
+fit one chip's HBM and never copy its KV cache out to the query heads.
+Nothing runs; a compile the chip's compiler refuses fails here, at no chip
+time.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU compiler's library."""
@@ -119,16 +120,23 @@ def test_decode_attention_compiles_at_qwen3_4b_widths(one_chip):
     ).lower(*args).compile()
 
 
-def test_qwen3_4b_decode_chunk_fits_one_chip(one_chip):
-    """One Engine decode chunk (8 slots, 2048-token cache, 8 steps, health
-    detectors on) at published widths, from lm.init's own abstract params:
-    the bf16 serving params are what make it fit (fp32 would need ~27 GB)."""
+QWEN3_4B_SLOTS, QWEN3_4B_LINES = 12, 2048  # the benchmark's slot pool
+
+
+@pytest.fixture(scope="module")
+def qwen3_4b_decode_chunk(topo):
+    """One Engine decode chunk (12 slots, 2048-token cache, 8 steps, health
+    detectors on) at published widths, from lm.init's own abstract params,
+    compiled once for the tests below."""
+    from jax.sharding import SingleDeviceSharding
+
     from repro.configs import get_config
     from repro.models import lm
 
+    one_chip = SingleDeviceSharding(topo.devices[0])
     cfg = get_config("qwen3-4b", sqrt_unit="e2afs")
     params, _ = lm.init(cfg, jax.random.key(0), abstract=True)
-    pool = lm.init_pool_state(cfg, 8, 2048, abstract=True)
+    pool = lm.init_pool_state(cfg, QWEN3_4B_SLOTS, QWEN3_4B_LINES, abstract=True)
     on_chip = lambda tree: jax.tree.map(  # noqa: E731
         lambda s: _shape(one_chip, s.shape, s.dtype), tree)
     state = [pool[k] for k in ("cache", "tok", "pos", "active", "remaining", "keys")]
@@ -137,7 +145,108 @@ def test_qwen3_4b_decode_chunk_fits_one_chip(one_chip):
             p, cfg, c, tok, pos, act, rem, 8, keys=keys, with_health=True),
         donate_argnums=(1, 2, 3, 4, 5),
     )
-    compiled = step.lower(on_chip(params), *on_chip(state)).compile()
+    return cfg, step.lower(on_chip(params), *on_chip(state)).compile()
+
+
+def test_qwen3_4b_decode_chunk_fits_one_chip(qwen3_4b_decode_chunk):
+    """The bf16 serving params are what make the chunk fit (fp32 would need
+    ~27 GB)."""
+    _, compiled = qwen3_4b_decode_chunk
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < V5E_HBM_BYTES, f"{used / 1e9:.2f} GB"
+
+
+def test_qwen3_4b_decode_chunk_never_expands_the_cache(qwen3_4b_decode_chunk):
+    """Decode attention contracts each kv head against its query group: the
+    optimised program holds no copy of the cache repeated to the query
+    heads, neither grouped (b, t, kv, g, hd) nor flat (b, t, h, hd)."""
+    cfg, compiled = qwen3_4b_decode_chunk
+    b, t, kv, hd = QWEN3_4B_SLOTS, QWEN3_4B_LINES, cfg.n_kv_heads, cfg.d_head
+    g = cfg.n_heads // kv
+    hlo = compiled.as_text()
+    for shape in ((b, t, kv, g, hd), (b, t, kv * g, hd)):
+        dims = "[" + ",".join(map(str, shape)) + "]"
+        assert dims not in hlo, f"cache expanded to {dims}"
+
+
+def test_qwen3_4b_decode_attention_temporaries_under_one_layer_cache(one_chip):
+    """One layer's decode attention over the 12-slot pool needs less scratch
+    than that layer's K and V: it reads the cache where it lies."""
+    from repro.configs import get_config
+    from repro.layers import attention as attn
+
+    cfg = get_config("qwen3-4b", sqrt_unit="e2afs")
+    b, t, d = QWEN3_4B_SLOTS, QWEN3_4B_LINES, cfg.d_model
+    h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
+    bf16 = jnp.bfloat16
+    p = {"wq": ((d, h, hd), bf16), "wk": ((d, kv, hd), bf16),
+         "wv": ((d, kv, hd), bf16), "wo": ((h, hd, d), bf16),
+         "q_norm": ((hd,), bf16), "k_norm": ((hd,), bf16)}
+    p = {name: _shape(one_chip, s, dt) for name, (s, dt) in p.items()}
+    cache = {name: _shape(one_chip, (b, t, kv, hd), bf16) for name in ("k", "v")}
+    x = _shape(one_chip, (b, 1, d), bf16)
+    pos = _shape(one_chip, (b,), jnp.int32)
+    compiled = jax.jit(
+        lambda p, x, cache, pos: attn.attention_decode(p, cfg, x, cache, pos),
+        donate_argnums=(2,),
+    ).lower(p, x, cache, pos).compile()
+    layer_cache = 2 * b * t * kv * hd * jnp.dtype(bf16).itemsize
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < layer_cache, f"{temp / 1e6:.1f} MB of scratch"
+
+
+@pytest.fixture(scope="module")
+def model16(topo):
+    """A (data=1, model=16) mesh on a described v5e:4x4, the model axis of
+    the production serving mesh."""
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:4x4")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:4x4 topology can be described here: {e}")
+    return Mesh(np.asarray(desc.devices).reshape(1, 16), ("data", "model"))
+
+
+@pytest.mark.parametrize("b,sq,t", [(4, 1, 256), (1, 128, 128)], ids=["decode", "prefill"])
+@pytest.mark.parametrize("name", ["starcoder2-15b", "qwen3-4b"])
+def test_serving_scores_stay_sharded_on_model16(model16, name, b, sq, t):
+    """Under serve rules on a 16-wide model axis, where neither kv heads nor
+    the query group divide 16 (starcoder2-15b 4 x 12, qwen3-4b 8 x 4) but
+    h does, the serving block keeps the scores split over the query heads:
+    no fp32 tensor of the per-device program spans all h heads, flat (a
+    dim of h) or grouped (dims kv, g side by side)."""
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.configs import get_config
+    from repro.distributed.constraints import axis_rules, logical_to_spec
+    from repro.distributed.sharding import divisible_spec, serve_rules
+    from repro.layers import attention as attn
+
+    cfg = get_config(name)
+    rules = serve_rules(cfg, model16)
+    h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
+
+    def arg(axes, shape, dtype=jnp.bfloat16):
+        spec = divisible_spec(logical_to_spec(axes, rules), shape, model16)
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(model16, spec))
+
+    def block(q, k, v, mask):
+        with axis_rules(model16, rules):
+            return attn._fold_masked_attention(q, k, v, mask, hd**-0.5, None, None,
+                                               jnp.bfloat16)
+
+    cache = arg(("batch", None, "kv_heads", None), (b, t, kv, hd))
+    hlo = jax.jit(block).lower(
+        arg(("batch", "seq", "heads", None), (b, sq, h, hd)), cache, cache,
+        jax.ShapeDtypeStruct((b, sq, t), jnp.float32,
+                             sharding=NamedSharding(model16, PartitionSpec())),
+    ).compile().as_text()
+    whole = [s for s in set(re.findall(r"f32\[([0-9,]+)\]", hlo))
+             if str(h) in s.split(",") or f"{kv},{h // kv}" in s]
+    assert not whole, f"fp32 tensors over all {h} heads: {whole}"
