@@ -39,18 +39,17 @@ def _seeds(text: str) -> list[int]:
 
 def weight_mismatches(cell: dict, seed: int) -> dict:
     """{leaf path: elements that differ} between the program's one-call
-    draw and the reference's redraw, every layer and top-level leaf."""
+    draw, on the cell's mesh if it has one, and the reference's redraw on
+    the first device, every layer and top-level leaf."""
     import jax
     import jax.numpy as jnp
 
-    from bench import weights
     from bench.reference import Reference
-    from repro.models import lm
 
     cfg = harness.program_config(cell["config"])
-    abstract, _ = lm.init(cfg, jax.random.PRNGKey(0), abstract=True)
-    prog = weights.program_params(seed, abstract, cfg.n_layers)
+    prog = harness.program_weights(cfg, seed, harness.program_mesh(cell["config"]))
     ref = Reference(cell["config"]["model"], seed)
+    here = jax.devices()[0]
     stacked = {"/".join(str(k.key) for k in path): leaf
                for path, leaf in jax.tree_util.tree_leaves_with_path(prog["layers"])}
     out = dict.fromkeys(stacked, 0)
@@ -58,13 +57,15 @@ def weight_mismatches(cell: dict, seed: int) -> dict:
         w = ref._draw_layer(ref.words, jnp.int32(layer))
         for path, leaf in stacked.items():
             name = path.rsplit("/", 1)[-1]
-            mine = w["mlp_" + name] if path.startswith("mlp/") else w[name]
-            out[path] += int(jnp.sum(leaf[layer].astype(jnp.float32) != mine))
+            theirs = w["mlp_" + name] if path.startswith("mlp/") else w[name]
+            mine = jax.device_put(leaf[layer], here).astype(jnp.float32)
+            out[path] += int(jnp.sum(mine != theirs))
         del w
     top = ref._draw_top(ref.words)
     for k in ("embed", "unembed", "ln_f", "ln_f_scale", "ln_f_bias"):
         if k in prog:
-            mine = prog[k][:, : ref.vocab] if k == "unembed" else prog[k]
+            mine = jax.device_put(prog[k], here)
+            mine = mine[:, : ref.vocab] if k == "unembed" else mine
             out[k] = int(jnp.sum(mine.astype(jnp.float32) != top[k]))
     return out
 

@@ -15,7 +15,18 @@ count the work a request needs, whatever implements it:
 
 Padding rows, whole-cache reads, masked positions and recomputation are not
 counted, so a change that stops doing them raises a share and none can pass
-100% by counting work that was not needed.
+100% by counting work that was not needed.  Biases (the MLP's, and the
+attention projections' of a model with ``use_bias``) are left out: under
+0.01% of the work.
+
+The counts are the whole model's, however many chips share it.  On a mesh
+of ``n`` chips a share divides them by ``n`` times one chip's peak
+(bench/run.py ``read_per_layer`` hands the readers those peaks) and by the
+time of one device, which runs its part of every program in step with the
+others: it is the share of all ``n`` chips' roofline, or peak, that the
+work needed.  Work that tensor parallelism repeats on every chip (norms,
+the replicated activations) and the exchanges between chips count for
+nothing.
 """
 from __future__ import annotations
 

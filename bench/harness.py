@@ -5,15 +5,18 @@ The system under test is the program's ``Engine`` (src/repro/launch/
 engine.py), fed weights that bench/weights.py draws from the seed and
 requests that bench/traffic/generator.py draws from the mix.  The run:
 
-1. draws the weights on the device in one jitted call;
-2. builds one ``Engine`` and warms up the prompt-length buckets this trace
-   uses and one decode chunk (compiled programs come from JAX's persistent
-   cache after a checkout's first run);
+1. draws the weights on the device in one jitted call: on the mesh that
+   the configuration's ``engine.mesh`` names, straight onto the shardings
+   the engine serves them from;
+2. builds one ``Engine`` (on that mesh, if any) and warms up the
+   prompt-length buckets this trace uses and one decode chunk (compiled
+   programs come from JAX's persistent cache after a checkout's first run);
 3. serves the trace through ``Engine.run``; a ``Telemetry`` that keeps each
    chunk boundary's time in memory is the only thing the engine is handed
    besides the requests;
-4. reads the peak device memory, frees the program's state, and runs the
-   plain reference over a sample of the served requests (bench/check.py).
+4. reads the peak memory of the fullest device, frees the program's state
+   on every device, and runs the plain reference, on the first device, over
+   a sample of the served requests (bench/check.py).
 
 With ``trace=True`` the profiler records a few seconds in mid-window, and
 the benchmark's own ``TraceAnnotation`` spans mark what the host was doing:
@@ -35,8 +38,8 @@ import numpy as np
 from bench import spec
 from bench.traffic.generator import make_trace
 
-__all__ = ["chunk_clock", "program_config", "serve", "first_token_times",
-           "end_to_end", "TRACE_DIR"]
+__all__ = ["chunk_clock", "program_config", "program_mesh", "program_weights",
+           "serve", "first_token_times", "end_to_end", "TRACE_DIR"]
 
 TRACE_DIR = spec.ROOT / ".bench_traces"
 
@@ -58,8 +61,13 @@ def _src_on_path(root: Path) -> None:
 
 def program_config(config: dict):
     """The program's ModelConfig for a configuration file, checked against
-    the file's model block: a width that differs is an error."""
+    the file's model block: a width that differs is an error, and so is a
+    ``use_bias`` that the program's tree disagrees with (it holds
+    ``layers/attn/bq`` exactly when the model has attention biases)."""
+    import jax
+
     from repro.configs import get_config
+    from repro.models import lm
 
     prog = config["program"]
     over = {k: tuple(v) if isinstance(v, list) else v
@@ -79,7 +87,43 @@ def program_config(config: dict):
         raise ValueError(f"{config['name']}: sliding window set, blocks {set(cfg.blocks)}")
     if cfg.act_dtype != m["torch_dtype"]:
         raise ValueError(f"{config['name']}: dtype {cfg.act_dtype} != {m['torch_dtype']}")
+    abstract, _ = lm.init(cfg, jax.random.PRNGKey(0), abstract=True)
+    has_bias = "bq" in abstract.get("layers", {}).get("attn", {})
+    if bool(m.get("use_bias", False)) != has_bias:
+        raise ValueError(f"{config['name']}: model use_bias={m.get('use_bias')!r} but the "
+                         f"program's tree {'has' if has_bias else 'has no'} layers/attn/bq")
     return cfg
+
+
+def program_mesh(config: dict):
+    """The device mesh that the configuration's ``engine.mesh`` names (axis
+    name -> size, in the program's axis names), over the first devices; None
+    without one, and the program then runs on one device."""
+    axes = config["engine"].get("mesh")
+    if not axes:
+        return None
+    from repro.launch.mesh import make_mesh_for
+
+    return make_mesh_for(tuple(axes.values()), tuple(axes))
+
+
+def program_weights(cfg, seed: int, mesh=None):
+    """The program's weights drawn from the seed in one jitted call
+    (bench/weights.py).  On a mesh each leaf lands on the sharding that
+    ``Engine`` commits it to (its default rules, ``serve_rules``), so the
+    engine's own placement moves nothing and no second copy is made."""
+    import jax
+
+    from bench import weights
+    from repro.models import lm
+
+    abstract, specs = lm.init(cfg, jax.random.PRNGKey(0), abstract=True)
+    shardings = None
+    if mesh is not None:
+        from repro.distributed.sharding import serve_rules, shardings_for
+
+        shardings = shardings_for(specs, mesh, serve_rules(cfg, mesh), abstract)
+    return weights.program_params(seed, abstract, cfg.n_layers, shardings)
 
 
 def chunk_clock(on_chunk=None):
@@ -202,14 +246,15 @@ def serve(cell: dict, seed: int, seconds: float, *, trace: bool, t_start: float,
     import jax
     from jax.profiler import TraceAnnotation
 
-    from bench import weights
     from repro.launch.engine import Engine, Request
-    from repro.models import lm
 
+    # op metadata (the named scopes the trace readers look for) goes into a
+    # program's cache key, so a cached program carries this build's scopes
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     config, mix = cell["config"], cell["traffic"]
     cfg = program_config(config)
-    abstract, _ = lm.init(cfg, jax.random.PRNGKey(0), abstract=True)
-    params = weights.program_params(seed, abstract, cfg.n_layers)
+    mesh = program_mesh(config)
+    params = program_weights(cfg, seed, mesh)
     jax.block_until_ready(params)
     log(f"weights drawn: {time.perf_counter() - t_start:.2f} s since start")
 
@@ -242,7 +287,7 @@ def serve(cell: dict, seed: int, seconds: float, *, trace: bool, t_start: float,
     eng_cfg = config["engine"]
     eng = Engine(params, cfg, num_slots=eng_cfg["num_slots"],
                  cache_len=eng_cfg["cache_len"], chunk=eng_cfg["chunk"],
-                 telemetry=clock)
+                 telemetry=clock, mesh=mesh)
     reqs = make_trace(mix, seconds, seed, cfg.vocab)
     eng.warmup(sorted({len(r.prompt) for r in reqs}))
     jax.block_until_ready(eng._cache)
@@ -265,12 +310,13 @@ def serve(cell: dict, seed: int, seconds: float, *, trace: bool, t_start: float,
             pass
         jax.profiler.stop_trace()
         state["closed"] = True
-    stats = jax.devices()[0].memory_stats() or {}
+    devices = jax.devices()[:1] if mesh is None else list(mesh.devices.flat)
     out = {
         "completions": done,
         "records": list(clock.records),
         "setup_s": setup_s,
-        "memory_peak_bytes": int(stats.get("peak_bytes_in_use", 0)),
+        "memory_peak_bytes": max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+                                 for d in devices),
         "requests": reqs,
         "compiles_in_window": compiles,
         "engine_stats": dict(eng.stats),
@@ -286,6 +332,8 @@ def serve(cell: dict, seed: int, seconds: float, *, trace: bool, t_start: float,
         shutil.rmtree(trace_dir, ignore_errors=True)
     del eng, params
     gc.collect()
+    log("bytes in use after freeing the program's state, by device: "
+        + " ".join(str((d.memory_stats() or {}).get("bytes_in_use")) for d in devices))
     return out
 
 

@@ -121,6 +121,7 @@ class Reference:
         self.eps = model["norm_eps"]
         self.window = model.get("sliding_window")
         self.theta = float(model["rope_theta"])
+        self.bias = bool(model.get("use_bias", False))
 
     # -- weights, drawn again from the seed ---------------------------------
 
@@ -130,6 +131,9 @@ class Reference:
              "layers/attn/wv": (d, kv, hd), "layers/attn/wo": (h, hd, d)}
         if self.m.get("qk_norm"):
             s.update({"layers/attn/q_norm": (hd,), "layers/attn/k_norm": (hd,)})
+        if self.bias:
+            s.update({"layers/attn/bq": (h, hd), "layers/attn/bk": (kv, hd),
+                      "layers/attn/bv": (kv, hd), "layers/attn/bo": (d,)})
         if self.rms:
             s.update({"layers/ln1": (d,), "layers/ln2": (d,)})
         else:
@@ -200,11 +204,15 @@ class Reference:
         q = _mm(a, w["wq"].reshape(d, h * hd), low).reshape(p, h, hd)
         k = _mm(a, w["wk"].reshape(d, kv * hd), low).reshape(p, kv, hd)
         v = _mm(a, w["wv"].reshape(d, kv * hd), low).reshape(p, kv, hd)
+        if self.bias:
+            q, k, v = q + w["bq"], k + w["bk"], v + w["bv"]
         if self.m.get("qk_norm"):
             q = _rms(q, w["q_norm"], self.eps)
             k = _rms(k, w["k_norm"], self.eps)
         q, k = _rope(q, pos, self.theta), _rope(k, pos, self.theta)
         x = x + _mm(self._attend(q, k, v, pos), w["wo"].reshape(h * hd, d), low)
+        if self.bias:
+            x = x + w["bo"]
         a = self._norm(x, w, "ln2")
         if self.m["hidden_act"] == "silu":
             m = jax.nn.silu(_mm(a, w["mlp_wi_gate"], low)) * _mm(a, w["mlp_wi_up"], low)

@@ -63,8 +63,10 @@ def read_per_layer(cell: dict, served: dict, first: dict, peaks: dict) -> dict:
     None and its metric is left out.  A reader is handed the run's
     completions and first-token times, the reduced trace (None without
     one), the engine-clock time the profiler started (None without a
-    trace), the configuration's model and engine blocks and the chip's
-    peaks."""
+    trace), the configuration's model and engine blocks, the number of
+    chips the program runs on, and their peaks: one chip's times that
+    number, so a share of a roofline or of the peak is of all of them."""
+    n = spec.chips(cell["config"])
     ctx = {
         "completions": served["completions"],
         "first": first,
@@ -73,7 +75,8 @@ def read_per_layer(cell: dict, served: dict, first: dict, peaks: dict) -> dict:
         "trace_opened_s": served.get("trace_opened_s"),
         "model": cell["config"]["model"],
         "engine": cell["config"]["engine"],
-        "peaks": peaks,
+        "chips": n,
+        "peaks": {k: v * n for k, v in peaks.items()},
     }
     out = {}
     for m in cell["per_layer"]:
@@ -81,6 +84,22 @@ def read_per_layer(cell: dict, served: dict, first: dict, peaks: dict) -> dict:
         if v is not None:
             out[m["name"]] = {"value": float(v), "unit": m["unit"]}
     return out
+
+
+def _log_program_trace(trace: dict, engine: dict, log) -> None:
+    """The traced window's device idle time by the engine span the host was
+    in, and the decode step by named scope with its unscoped share."""
+    from bench import program_trace
+
+    idle = program_trace.idle_by_span(trace)
+    if idle is not None:
+        log("device idle by engine span (s): "
+            + " ".join(f"{k} {v}" for k, v in sorted(idle.items(), key=lambda kv: -kv[1])))
+    split = program_trace.decode_split({"trace": trace, "engine": engine})
+    if split is not None:
+        log("decode step by scope (ms): "
+            + " ".join(f"{k or 'unscoped'} {v}" for k, v in split.items())
+            + f"; unscoped share {split[None] / sum(split.values())}")
 
 
 def run_cell(cell: dict, seed: int, seconds: float, trace: bool, device: dict,
@@ -103,6 +122,8 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, device: dict,
         f"{json.dumps({k: v for k, v in served['engine_stats'].items() if isinstance(v, (int, float))})}")
     if trace:
         metrics = read_per_layer(cell, served, first, peaks)
+        if served["trace_data"] is not None:
+            _log_program_trace(served["trace_data"], cell["config"]["engine"], log)
     else:
         e2e = harness.end_to_end(done, first, served["records"], seconds)
         e2e["setup_s"] = served["setup_s"]

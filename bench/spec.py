@@ -5,14 +5,20 @@ is an entry of ``workloads``; its configuration is
 ``bench/configs/<config>.json``, its mix ``bench/traffic/<traffic>.json``,
 its correctness limits ``bench/limits/<cell>.json``, and each per-layer
 metric ``bench/metrics/<metric>.py``.  Adding any of them is adding a file.
+
+A configuration may name a device mesh, ``engine.mesh``: axis name -> size
+in the program's axis names, such as ``{"data": 1, "model": 4}``.  Its size
+is the cell's ``chips``; a cell whose configuration names none runs on one
+device.
 """
 from __future__ import annotations
 
 import importlib.util
 import json
+import math
 from pathlib import Path
 
-__all__ = ["ROOT", "load", "cell", "metric_reader"]
+__all__ = ["ROOT", "load", "cell", "chips", "metric_reader"]
 
 ROOT = Path(__file__).resolve().parents[1]
 
@@ -38,6 +44,10 @@ def cell(name: str, root: Path = ROOT) -> dict:
     configs = {c["name"]: c for c in bench["configs"]}
     cfg_entry = configs[w["config"]]
     config = _json(root / cfg_entry["file"])
+    if "mesh" in config["engine"] and chips(config) != w["chips"]:
+        raise ValueError(f"{name}: the mesh {config['engine']['mesh']} of "
+                         f"{cfg_entry['file']} spans {chips(config)} devices, the "
+                         f"cell asks for {w['chips']} chips")
 
     def applies(metric):
         return "workloads" not in metric or name in metric["workloads"]
@@ -56,6 +66,11 @@ def cell(name: str, root: Path = ROOT) -> dict:
         "per_layer": per_layer,
         "root": root,
     }
+
+
+def chips(config: dict) -> int:
+    """The devices a configuration's program runs on: its mesh's size, or 1."""
+    return math.prod(config["engine"].get("mesh", {}).values())
 
 
 def metric_reader(name: str, root: Path = ROOT):

@@ -56,18 +56,16 @@ def main(argv=None) -> int:
         print(f"refused: {e}", file=sys.stderr)
         return 3
     harness.enable_compile_cache(cell["root"])
-    from bench import weights
     from bench.traffic.generator import make_trace
     from repro.launch.engine import Engine, Request
-    from repro.models import lm
 
     cfg = harness.program_config(cell["config"])
-    abstract, _ = lm.init(cfg, jax.random.PRNGKey(0), abstract=True)
-    params = weights.program_params(args.seed, abstract, cfg.n_layers)
+    mesh = harness.program_mesh(cell["config"])
+    params = harness.program_weights(cfg, args.seed, mesh)
     clock = harness.chunk_clock()
     e = cell["config"]["engine"]
     eng = Engine(params, cfg, num_slots=e["num_slots"], cache_len=e["cache_len"],
-                 chunk=e["chunk"], telemetry=clock)
+                 chunk=e["chunk"], telemetry=clock, mesh=mesh)
     mix = cell["traffic"]
     for rate in (float(r) for r in args.rates.split(",")):
         m = dict(mix, arrival=dict(mix["arrival"], rate_rps=rate))

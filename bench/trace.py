@@ -7,14 +7,17 @@ as a test fixture exercises the same code as a run on the chip::
     {"window": [t0, t1],                    # ns, from the benchmark's marks
      "devices": [{"ops": [[name, t0, t1], ...],
                   "modules": [[name, t0, t1], ...]}, ...],
-     "host": [[name, t0, t1], ...]}         # the benchmark's own spans
+     "host": [[name, t0, t1], ...],         # the benchmark's own spans
+     "program": [[name, t0, t1], ...],      # the program's engine.* spans
+     "scopes": [{module: {op: scope}}, ...]}  # per device, named scopes
 
 Device events come from the planes ``/device:TPU:<n>``: the line
 ``XLA Ops`` holds one event per operation and ``XLA Modules`` one per
 executed program.  Host spans are the ``jax.profiler.TraceAnnotation``
 spans whose names start with ``bench.``: ``bench.trace_open`` and
 ``bench.trace_close`` bound the window, and the rest say what the host was
-doing (see bench/harness.py).
+doing (see bench/harness.py).  ``program`` and ``scopes`` are
+bench/program_trace.py's reading of the same file.
 """
 from __future__ import annotations
 
@@ -73,7 +76,11 @@ def read_xplane(trace_dir: str) -> dict:
     for dev in devices:
         for e in dev["modules"] + dev["ops"]:
             t0, t1 = min(t0, e[1]), max(t1, e[2])
-    return {"window": [t0, t1], "devices": devices, "host": host}
+    from bench import program_trace
+
+    out = {"window": [t0, t1], "devices": devices, "host": host}
+    out.update(program_trace.read(paths[-1]))
+    return out
 
 
 def clip(events, window):

@@ -19,8 +19,19 @@ give it within a factor of sqrt(2)):
 * the token embedding: 0.02 (tied: also the unembedding);
 * an untied unembedding: 1/sqrt(d_model), so logits have unit scale;
 * RMSNorm gains (stored as ``scale`` with weight ``1 + scale``), LayerNorm
-  ``bias`` and MLP biases: 0.1, 0.02, 0.02 around 0; LayerNorm ``scale``:
-  0.1 around 1.
+  ``bias``, MLP and attention biases: 0.1, 0.02, 0.02 around 0; LayerNorm
+  ``scale``: 0.1 around 1.
+
+Attention biases (a model block with ``use_bias``, as StarCoder2's) are the
+leaves ``layers/attn/bq`` (heads, head_dim), ``layers/attn/bk`` and
+``layers/attn/bv`` (kv_heads, head_dim), added to the q, k and v
+projections before RoPE, and ``layers/attn/bo`` (d_model,), added to the
+output projection: the names and shapes a program's tree uses for them.
+
+On a mesh the one jitted call takes the program's serving shardings as its
+``out_shardings``: each device draws only its shard, and the values are
+those of the one-device draw bit for bit (the random bits do not depend on
+how the draw is partitioned).
 """
 from __future__ import annotations
 
@@ -48,6 +59,9 @@ _RULES = {
     "wi_up": (0.0, "first"),
     "bi": (0.0, 0.02),
     "bo": (0.0, 0.02),
+    "bq": (0.0, 0.02),
+    "bk": (0.0, 0.02),
+    "bv": (0.0, 0.02),
     "ln1": (0.0, 0.1),
     "ln2": (0.0, 0.1),
     "ln_f": (0.0, 0.1),
@@ -147,12 +161,14 @@ def _unflatten(flat: dict) -> dict:
     return root
 
 
-def program_params(seed: int, abstract_tree: dict, n_layers: int):
+def program_params(seed: int, abstract_tree: dict, n_layers: int, shardings=None):
     """The program's parameter tree, drawn on the device in one jitted call.
 
     ``abstract_tree`` is the tree of ShapeDtypeStructs the program expects
     (leaves under ``layers/`` carry a leading stacked-layers axis); the
-    values come from :func:`leaf` alone."""
+    values come from :func:`leaf` alone.  ``shardings``, a matching tree,
+    places each leaf where the call leaves it; without it every leaf lands
+    on the default device."""
     flat = _flatten(abstract_tree)
     for p, s in flat.items():
         if s.dtype != DTYPE:
@@ -175,4 +191,6 @@ def program_params(seed: int, abstract_tree: dict, n_layers: int):
                 out[p] = leaf(key_of(words, p, -1), shape, *leaf_spec(p, shape))
         return out
 
-    return _unflatten(jax.jit(make)(seed_words(seed)))
+    draw = (jax.jit(make) if shardings is None
+            else jax.jit(make, out_shardings=_flatten(shardings)))
+    return _unflatten(draw(seed_words(seed)))

@@ -1,5 +1,5 @@
 """A tiny benchmark root for the CPU tests: the real BENCHMARK.json with
-two toy cells added, toy configuration, mix and limit files beside it, and
+three toy cells added, toy configuration, mix and limit files beside it, and
 the real metric readers and peaks.  The code is the repository's ``bench``
 package; only the data files live under ``root``."""
 import json
@@ -14,8 +14,10 @@ if str(REPO) not in sys.path:
 TOY_WIDTHS = dict(hidden_size=64, intermediate_size=128, num_hidden_layers=2,
                   num_attention_heads=4, num_key_value_heads=2, head_dim=16,
                   vocab_size=256)
-_PROGRAM = dict(d_model=64, d_ff=128, n_layers=2, n_heads=4, n_kv_heads=2,
-                d_head=16, vocab=256)
+# the model block's widths -> the program's config fields
+_PROGRAM = dict(hidden_size="d_model", intermediate_size="d_ff",
+                num_hidden_layers="n_layers", num_attention_heads="n_heads",
+                num_key_value_heads="n_kv_heads", head_dim="d_head", vocab_size="vocab")
 
 CHAT = {"arrival": {"process": "poisson", "rate_rps": 8.0},
         "prompt": {"dist": "lognormal", "median": 12, "sigma": 0.8, "buckets": [8, 16, 32]},
@@ -28,10 +30,14 @@ CODE = {"arrival": {"process": "gamma", "cv": 3.0, "rate_rps": 8.0},
 
 # Toy limits on the widest logit gap of served tokens, set like the cells'
 # (PERF.md): between the widest gap of sound runs over seeds 1-8 (toy-qwen
-# 0.0063, toy-sc 0.034) and the narrowest fp8-control gap over seeds 1-4
-# (0.044 and 0.27), on a host CPU.
+# 0.0063, toy-sc 0.034, toy-sc-tp4 0.026) and the narrowest fp8-control gap
+# over seeds 1-4 (0.044, 0.27 and 0.26), on a host CPU.
 LIMITS = {"toy-qwen.chat": {"logit_gap": 0.02, "not_served": 0},
-          "toy-sc.code": {"logit_gap": 0.1, "not_served": 0}}
+          "toy-sc.code": {"logit_gap": 0.1, "not_served": 0},
+          "toy-sc-tp4.code": {"logit_gap": 0.1, "not_served": 0}}
+
+# the (data 1, model 4) mesh of tests/conftest.py's four host devices
+MESH = {"data": 1, "model": 4}
 
 # a LayerNorm, GELU, sliding-window model with untied embeddings (the
 # StarCoder2 family), so the toy cells cover both norms and the window ring
@@ -49,7 +55,7 @@ def _toy_config(base: dict, name: str, **model) -> dict:
     c = json.loads(json.dumps(base))
     c["name"] = name
     c["model"].update(TOY_WIDTHS, **model)
-    over = dict(_PROGRAM)
+    over = {v: c["model"][k] for k, v in _PROGRAM.items()}
     if model.get("sliding_window"):
         over["window"] = model["sliding_window"]
     c["program"]["overrides"].update(over)
@@ -58,8 +64,10 @@ def _toy_config(base: dict, name: str, **model) -> dict:
 
 def make_root(tmp: Path) -> Path:
     """Write the toy root under ``tmp``; cells ``toy-qwen.chat`` (RMSNorm,
-    qk-norm, tied) and ``toy-sc.code`` (LayerNorm, window 32, prompts past
-    the window)."""
+    qk-norm, tied), ``toy-sc.code`` (LayerNorm, window 32, prompts past
+    the window) and ``toy-sc-tp4.code``: the same model with 8 query and 4
+    KV heads, served on :data:`MESH`, so every device holds a quarter of
+    the heads, the KV heads, the MLP and the vocabulary."""
     root = Path(tmp) / "root"
     (root / "bench").mkdir(parents=True)
     shutil.copytree(REPO / "bench" / "metrics", root / "bench" / "metrics")
@@ -71,10 +79,15 @@ def make_root(tmp: Path) -> Path:
     q["engine"] = dict(num_slots=4, cache_len=128, chunk=4)
     s = _toy_config(_SC_BASE, "toy-sc", sliding_window=32)
     s["engine"] = dict(num_slots=4, cache_len=32, chunk=4)
+    t = _toy_config(_SC_BASE, "toy-sc-tp4", sliding_window=32, num_attention_heads=8,
+                    num_key_value_heads=4)
+    t["engine"] = dict(num_slots=4, cache_len=32, chunk=4, mesh=MESH)
     files = {"configs/toy-qwen.json": q, "configs/toy-sc.json": s,
+             "configs/toy-sc-tp4.json": t,
              "traffic/chat.json": CHAT, "traffic/code.json": CODE,
              "limits/toy-qwen.chat.json": LIMITS["toy-qwen.chat"],
-             "limits/toy-sc.code.json": LIMITS["toy-sc.code"]}
+             "limits/toy-sc.code.json": LIMITS["toy-sc.code"],
+             "limits/toy-sc-tp4.code.json": LIMITS["toy-sc-tp4.code"]}
     for rel, obj in files.items():
         (root / "bench" / rel).write_text(json.dumps(obj))
     b = json.loads((REPO / "BENCHMARK.json").read_text())
@@ -82,10 +95,14 @@ def make_root(tmp: Path) -> Path:
         {"name": "toy-qwen", "source": "test", "file": "bench/configs/toy-qwen.json",
          "reduced": [], "why": "toy"},
         {"name": "toy-sc", "source": "test", "file": "bench/configs/toy-sc.json",
+         "reduced": [], "why": "toy"},
+        {"name": "toy-sc-tp4", "source": "test", "file": "bench/configs/toy-sc-tp4.json",
          "reduced": [], "why": "toy"}]
     b["workloads"] += [
         {"name": "toy-qwen.chat", "config": "toy-qwen", "traffic": "chat", "chips": 1, "why": "toy"},
-        {"name": "toy-sc.code", "config": "toy-sc", "traffic": "code", "chips": 1, "why": "toy"}]
+        {"name": "toy-sc.code", "config": "toy-sc", "traffic": "code", "chips": 1, "why": "toy"},
+        {"name": "toy-sc-tp4.code", "config": "toy-sc-tp4", "traffic": "code", "chips": 4,
+         "why": "toy"}]
     (root / "BENCHMARK.json").write_text(json.dumps(b))
     return root
 

@@ -43,3 +43,15 @@ def test_width_that_differs_is_refused():
     c["model"]["intermediate_size"] = 9729
     with pytest.raises(ValueError, match="intermediate_size"):
         harness.program_config(c)
+
+
+def test_use_bias_the_program_lacks_is_refused():
+    """The program's tree has no attention biases, so a model block that
+    sets ``use_bias`` (as StarCoder2's published config does) is refused by
+    name, and one that says false passes."""
+    c = _config("qwen3-4b")
+    c["model"]["use_bias"] = True
+    with pytest.raises(ValueError, match="use_bias=True.*has no layers/attn/bq"):
+        harness.program_config(c)
+    c["model"]["use_bias"] = False
+    harness.program_config(c)

@@ -30,7 +30,7 @@ def root(tmp_path_factory):
     return make_root(tmp_path_factory.mktemp("ref"))
 
 
-@pytest.mark.parametrize("name", ["toy-qwen.chat", "toy-sc.code"])
+@pytest.mark.parametrize("name", ["toy-qwen.chat", "toy-sc.code", "toy-sc-tp4.code"])
 def test_reference_redraws_the_program_weights(root, name):
     cell = spec.cell(name, root)
     diff = weight_mismatches(cell, 2**33 + 5)
@@ -90,3 +90,69 @@ def test_control_reads_wider_than_a_sound_run(root):
     for r in ref_seeds:
         assert check.verdict(r, cell["limits"])[0] is True
         assert check.verdict(dict(r, logit_gap=r["control_gap"]), cell["limits"])[0] is False
+
+
+# one toy StarCoder2 block with attention biases: LayerNorm, GELU, GQA (4
+# query heads over 2 KV heads), a window of 5 over 8 positions
+BIASED = dict(hidden_size=32, intermediate_size=64, num_hidden_layers=2,
+              num_attention_heads=4, num_key_value_heads=2, head_dim=8, vocab_size=256,
+              hidden_act="gelu_pytorch_tanh", norm="layernorm", norm_eps=1e-5,
+              rope_theta=100000.0, sliding_window=5, tie_word_embeddings=False,
+              qk_norm=False, use_bias=True)
+
+
+def _loop_layer(w, x, m):
+    """The block token by token in float64 numpy, with the E2AFS-R rsqrt of
+    each float32 variance."""
+    w = {k: np.asarray(v, np.float64) for k, v in w.items()}
+    h, kv, hd, win = (m["num_attention_heads"], m["num_key_value_heads"], m["head_dim"],
+                      m["sliding_window"])
+    g, half = h // kv, hd // 2
+
+    def ln(v, name):
+        mu = v.mean()
+        var = np.float32(((v - mu) ** 2).mean() + m["norm_eps"])
+        return (v - mu) * float(rsqrt_e2afs(jnp.float32(var))) * w[f"{name}_scale"] + w[
+            f"{name}_bias"]
+
+    def rope(v, p):
+        ang = p / m["rope_theta"] ** (np.arange(half) / half)
+        a, b = v[..., :half], v[..., half:]
+        return np.concatenate([a * np.cos(ang) - b * np.sin(ang),
+                               b * np.cos(ang) + a * np.sin(ang)], axis=-1)
+
+    ks, vs, out = [], [], []
+    for t in range(x.shape[0]):
+        a = ln(x[t], "ln1")
+        q = rope(np.einsum("d,dhk->hk", a, w["wq"]) + w["bq"], t)
+        ks.append(rope(np.einsum("d,dhk->hk", a, w["wk"]) + w["bk"], t))
+        vs.append(np.einsum("d,dhk->hk", a, w["wv"]) + w["bv"])
+        seen = range(max(0, t - win + 1), t + 1)
+        att = np.zeros((h, hd))
+        for i in range(h):
+            s = np.array([q[i] @ ks[j][i // g] for j in seen]) / np.sqrt(hd)
+            p = np.exp(s - s.max())
+            att[i] = sum(pj * vs[j][i // g] for pj, j in zip(p / p.sum(), seen))
+        y = x[t] + np.einsum("hk,hkd->d", att, w["wo"]) + w["bo"]
+        u = ln(y, "ln2") @ w["mlp_wi_up"] + w["mlp_bi"]
+        u = 0.5 * u * (1 + np.tanh(np.sqrt(2 / np.pi) * (u + 0.044715 * u ** 3)))
+        out.append(y + u @ w["mlp_wo"] + w["mlp_bo"])
+    return np.stack(out)
+
+
+def test_reference_attention_biases_match_a_per_token_loop():
+    ref = Reference(BIASED, 2**32 + 3)
+    w = ref._draw_layer(ref.words, jnp.int32(1))
+    assert {"bq", "bk", "bv", "bo", "mlp_bo"} <= set(w)
+    x = np.random.default_rng(4).normal(size=(8, BIASED["hidden_size"])).astype(np.float32)
+    pos = jnp.arange(8, dtype=jnp.int32)
+    got = np.asarray(ref._layer(w, jnp.asarray(x), pos, False))
+    want = _loop_layer(w, x.astype(np.float64), BIASED)
+    # float32 rounding of sums over at most 64 terms of unit-scale values
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-5 * np.abs(want).max())
+    # the biases matter at that tolerance: the same weights without them differ
+    plain = Reference(dict(BIASED, use_bias=False), 2**32 + 3)
+    wp = plain._draw_layer(plain.words, jnp.int32(1))
+    assert "bq" not in wp
+    without = np.asarray(plain._layer(wp, jnp.asarray(x), pos, False))
+    assert np.abs(without - want).max() > 100 * 1e-5 * np.abs(want).max()

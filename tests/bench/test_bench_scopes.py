@@ -97,6 +97,38 @@ def test_read_spans_and_scopes_from_an_xspace(tmp_path):
     }]
 
 
+def test_read_xplane_adds_the_programs_marks_and_keeps_the_rest(tmp_path):
+    """bench/trace.py's record of an xplane holds program_trace.read's
+    ``program`` and ``scopes``; its window, device events and benchmark
+    spans read as they did without them."""
+    from jax.profiler import ProfileData
+
+    text = XSPACE.replace(
+        "events { metadata_id: 3 offset_ps: 1000 duration_ps: 1000 } }",
+        "events { metadata_id: 3 offset_ps: 1000 duration_ps: 1000 }\n"
+        "    events { metadata_id: 4 offset_ps: 90000 duration_ps: 1000 } }").replace(
+        'event_metadata { key: 3 value { id: 3 name: "bench.trace_open" } }',
+        'event_metadata { key: 3 value { id: 3 name: "bench.trace_open" } }\n'
+        '  event_metadata { key: 4 value { id: 4 name: "bench.trace_close" } }')
+    assert text.count("bench.trace_close") == 1
+    path = tmp_path / "plugins" / "profile" / "t" / "t.xplane.pb"
+    path.parent.mkdir(parents=True)
+    path.write_bytes(ProfileData.text_proto_to_serialized_xspace(text))
+    got = tr.read_xplane(str(tmp_path))
+    marks = pt.read(str(path))
+    assert got["program"] == marks["program"] and got["scopes"] == marks["scopes"]
+    assert got["program"] and got["scopes"][0]
+    rest = {k: v for k, v in got.items() if k not in marks}
+    assert rest == {
+        # from the end of bench.trace_open to the start of bench.trace_close
+        "window": [1002.0, 1090.0],
+        "devices": [{"ops": [["dot.3", 1030.0, 1050.0]],
+                     "modules": [["jit_decode_fn(8)", 1030.0, 1060.0]]}],
+        "host": [["bench.trace_open", 1001.0, 1002.0],
+                 ["bench.trace_close", 1090.0, 1091.0]],
+    }
+
+
 def test_innermost_scope_of_an_op_path():
     assert pt.innermost_scope("jit(decode_fn)/while/body/decode_attention/norm/mul") == "norm"
     assert pt.innermost_scope("jit(decode_fn)/while/body/decode_attention/dot_general") == (
