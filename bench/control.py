@@ -4,15 +4,17 @@
         --seeds 101-112 --control-seeds 3 --seconds 15
 
 First, on the first seed, the reference's redraw of the weights is compared
-with the program's draw, element by element, on the device: a leaf that
-differs would make the check compare the program with another model.  Then,
-for each seed, in one process: a run of the cell's timed path at its own
-load for a short window (every request served and drained), and the
-reference over the run's sample, as ``bench.run`` does.  The widest served
-gap over these sound runs is the lower reading.  On the first
-``--control-seeds`` seeds the reference is also run in fp8 (the control,
-bench/reference.py ``low=True``): the gap of the token it puts first at each
-of the same positions, the smallest of which is the upper reading.  Both go
+with the program's draw, element by element, on the device, leaf by
+program path (``attn/wq`` of each layer, whether the program stacks its
+layers or lists them): a leaf that differs would make the check compare
+the program with another model.  Then, for each seed, in one process: a
+run of the cell's timed path at its own load for a short window (every
+request served and drained), and the reference over the run's sample, as
+``bench.run`` does.  The widest served gap over these sound runs is the
+lower reading.  On the first ``--control-seeds`` seeds the reference is
+also run in fp8 (the control: the cell's architecture's ``Reference``
+with ``low=True``): the gap of the token it puts first at each of the
+same positions, the smallest of which is the upper reading.  Both go
 through the harness's own comparison (bench/check.py ``verdict``) with the
 limits in ``bench/limits/<cell>.json``, and each line prints the
 ``correct`` that a run would: true for a sound run, false for the control.
@@ -37,36 +39,51 @@ def _seeds(text: str) -> list[int]:
     return [int(s) for s in text.split(",")]
 
 
+def _layer_leaves(layers, i: int) -> dict:
+    """{path within a layer: array} of layer ``i`` of the program's tree,
+    whether its layers are stacked or listed."""
+    import jax
+
+    from bench import weights
+
+    if isinstance(layers, list):
+        return {weights.path_and_layer(k)[0]: v
+                for k, v in jax.tree_util.tree_leaves_with_path(layers[i])}
+    return {weights.path_and_layer(k)[0]: v[i]
+            for k, v in jax.tree_util.tree_leaves_with_path(layers)}
+
+
 def weight_mismatches(cell: dict, seed: int) -> dict:
-    """{leaf path: elements that differ} between the program's one-call
-    draw, on the cell's mesh if it has one, and the reference's redraw on
-    the first device, every layer and top-level leaf."""
+    """{program leaf path: elements that differ} between the program's
+    one-call draw, on the cell's mesh if it has one, and the reference's
+    redraw (``draw_layer``, ``draw_top``) on the first device, every layer
+    and top-level leaf, by program path over stacked and listed layers.  A
+    leaf the reference does not draw differs in every element."""
     import jax
     import jax.numpy as jnp
 
-    from bench.reference import Reference
-
-    cfg = harness.program_config(cell["config"])
-    prog = harness.program_weights(cfg, seed, harness.program_mesh(cell["config"]))
-    ref = Reference(cell["config"]["model"], seed)
+    arch = cell["arch"]
+    cfg = harness.program_config(cell["config"], arch)
+    prog = harness.program_weights(cfg, seed, harness.program_mesh(cell["config"]),
+                                   arch.RULES)
+    ref = arch.Reference(cell["config"]["model"], seed)
     here = jax.devices()[0]
-    stacked = {"/".join(str(k.key) for k in path): leaf
-               for path, leaf in jax.tree_util.tree_leaves_with_path(prog["layers"])}
-    out = dict.fromkeys(stacked, 0)
+
+    def differ(mine, theirs):
+        if theirs is None or theirs.shape != mine.shape:
+            return int(mine.size)
+        return int(jnp.sum(jax.device_put(mine, here).astype(jnp.float32) != theirs))
+
+    out: dict = {}
     for layer in range(cfg.n_layers):
-        w = ref._draw_layer(ref.words, jnp.int32(layer))
-        for path, leaf in stacked.items():
-            name = path.rsplit("/", 1)[-1]
-            theirs = w["mlp_" + name] if path.startswith("mlp/") else w[name]
-            mine = jax.device_put(leaf[layer], here).astype(jnp.float32)
-            out[path] += int(jnp.sum(mine != theirs))
+        w = ref.draw_layer(layer)
+        for path, leaf in _layer_leaves(prog["layers"], layer).items():
+            out[path] = out.get(path, 0) + differ(leaf, w.get(path))
         del w
-    top = ref._draw_top(ref.words)
-    for k in ("embed", "unembed", "ln_f", "ln_f_scale", "ln_f_bias"):
-        if k in prog:
-            mine = jax.device_put(prog[k], here)
-            mine = mine[:, : ref.vocab] if k == "unembed" else mine
-            out[k] = int(jnp.sum(mine.astype(jnp.float32) != top[k]))
+    top = ref.draw_top()
+    for k, leaf in prog.items():
+        if k != "layers":
+            out[k] = differ(leaf[:, : ref.vocab] if k == "unembed" else leaf, top.get(k))
     return out
 
 
@@ -87,8 +104,7 @@ def main(argv=None) -> int:
         print(f"refused: {e}", file=sys.stderr)
         return 3
     harness.enable_compile_cache(cell["root"])
-    from bench.reference import Reference
-
+    Reference = cell["arch"].Reference
     seeds = _seeds(args.seeds)
     mismatched = weight_mismatches(cell, seeds[0])
     print(json.dumps({"weights_seed": seeds[0], "elements_differing": mismatched}), flush=True)

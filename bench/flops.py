@@ -1,7 +1,9 @@
 """Operations and bytes the algorithm needs, computed from shapes alone.
 
-These are the numerators of every roofline share and of ``serve_mfu``.  They
-count the work a request needs, whatever implements it:
+These are the numerators of every roofline share and of ``serve_mfu``: a
+``Dims`` is the dense default's ``work(model)`` (bench/archs/), and an
+architecture module's own counts keep these rules.  They count the work a
+request needs, whatever implements it:
 
 * matrix FLOPs are 2 per weight per token over the non-embedding weights,
   plus the unembedding once per token whose logits are used (the last
@@ -74,6 +76,12 @@ class Dims:
 
     def attn_flops(self, attended: int) -> int:
         return 4 * self.heads * self.head_dim * self.layers * attended
+
+    def prefill(self, s: int) -> tuple[int, int]:
+        return prefill(self, s)
+
+    def decode_step(self, positions) -> tuple[int, int]:
+        return decode_step(self, positions)
 
 
 def dims(model: dict) -> Dims:

@@ -43,55 +43,31 @@ __all__ = ["chunk_clock", "program_config", "program_mesh", "program_weights",
 
 TRACE_DIR = spec.ROOT / ".bench_traces"
 
-# how the configuration file's model block maps onto the program's config
-_CHECKS = (
-    ("hidden_size", "d_model"), ("num_hidden_layers", "n_layers"),
-    ("num_attention_heads", "n_heads"), ("num_key_value_heads", "n_kv_heads"),
-    ("head_dim", "d_head"), ("intermediate_size", "d_ff"),
-    ("vocab_size", "vocab"), ("rope_theta", "rope_theta"),
-    ("tie_word_embeddings", "tie_embeddings"), ("sliding_window", "window"),
-)
-
-
 def _src_on_path(root: Path) -> None:
     src = str(Path(root) / "src")
     if src not in sys.path:
         sys.path.insert(0, src)
 
 
-def program_config(config: dict):
+def program_config(config: dict, arch=None):
     """The program's ModelConfig for a configuration file, checked against
-    the file's model block: a width that differs is an error, and so is a
-    ``use_bias`` that the program's tree disagrees with (it holds
-    ``layers/attn/bq`` exactly when the model has attention biases)."""
-    import jax
-
+    the file's model block by the architecture module's ``check_program``
+    (``arch``; without it the dense default, bench/archs/dense.py): a width,
+    block type or size that differs is an error."""
+    from bench.archs import dense
     from repro.configs import get_config
-    from repro.models import lm
 
     prog = config["program"]
     over = {k: tuple(v) if isinstance(v, list) else v
             for k, v in prog.get("overrides", {}).items()}
     cfg = get_config(prog["arch"], **over).validate()
     m = config["model"]
-    for mk, ck in _CHECKS:
-        if m.get(mk) != getattr(cfg, ck):
-            raise ValueError(f"{config['name']}: model {mk}={m.get(mk)!r} but the "
-                             f"program runs {ck}={getattr(cfg, ck)!r}")
-    want = {"norm": cfg.norm, "qk_norm": cfg.qk_norm,
-            "hidden_act": {"swiglu": "silu", "gelu": "gelu_pytorch_tanh"}[cfg.mlp_act]}
-    for k, v in want.items():
-        if m.get(k, False) != v:
-            raise ValueError(f"{config['name']}: model {k}={m.get(k)!r}, program {v!r}")
-    if m.get("sliding_window") and set(cfg.blocks) != {"window"}:
-        raise ValueError(f"{config['name']}: sliding window set, blocks {set(cfg.blocks)}")
+    try:
+        (arch or dense).check_program(m, cfg)
+    except ValueError as e:
+        raise ValueError(f"{config['name']}: {e}") from None
     if cfg.act_dtype != m["torch_dtype"]:
         raise ValueError(f"{config['name']}: dtype {cfg.act_dtype} != {m['torch_dtype']}")
-    abstract, _ = lm.init(cfg, jax.random.PRNGKey(0), abstract=True)
-    has_bias = "bq" in abstract.get("layers", {}).get("attn", {})
-    if bool(m.get("use_bias", False)) != has_bias:
-        raise ValueError(f"{config['name']}: model use_bias={m.get('use_bias')!r} but the "
-                         f"program's tree {'has' if has_bias else 'has no'} layers/attn/bq")
     return cfg
 
 
@@ -107,9 +83,10 @@ def program_mesh(config: dict):
     return make_mesh_for(tuple(axes.values()), tuple(axes))
 
 
-def program_weights(cfg, seed: int, mesh=None):
+def program_weights(cfg, seed: int, mesh=None, rules=None):
     """The program's weights drawn from the seed in one jitted call
-    (bench/weights.py).  On a mesh each leaf lands on the sharding that
+    (bench/weights.py), with the architecture module's weight ``rules``
+    before the defaults.  On a mesh each leaf lands on the sharding that
     ``Engine`` commits it to (its default rules, ``serve_rules``), so the
     engine's own placement moves nothing and no second copy is made."""
     import jax
@@ -123,7 +100,7 @@ def program_weights(cfg, seed: int, mesh=None):
         from repro.distributed.sharding import serve_rules, shardings_for
 
         shardings = shardings_for(specs, mesh, serve_rules(cfg, mesh), abstract)
-    return weights.program_params(seed, abstract, cfg.n_layers, shardings)
+    return weights.program_params(seed, abstract, cfg.n_layers, shardings, rules)
 
 
 def chunk_clock(on_chunk=None):
@@ -252,9 +229,9 @@ def serve(cell: dict, seed: int, seconds: float, *, trace: bool, t_start: float,
     # program's cache key, so a cached program carries this build's scopes
     jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     config, mix = cell["config"], cell["traffic"]
-    cfg = program_config(config)
+    cfg = program_config(config, cell["arch"])
     mesh = program_mesh(config)
-    params = program_weights(cfg, seed, mesh)
+    params = program_weights(cfg, seed, mesh, cell["arch"].RULES)
     jax.block_until_ready(params)
     log(f"weights drawn: {time.perf_counter() - t_start:.2f} s since start")
 

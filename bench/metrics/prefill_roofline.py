@@ -1,5 +1,6 @@
 """Admit/prefill: share of the admit program's device time that the chip's
-roofline needs for the prefills it ran (bench/flops.py ``prefill``), in %.
+roofline needs for the prefills it ran (the architecture's
+``work(model).prefill``), in %.
 Moves ``ttft_p90_ms``."""
 from bench import flops, work
 
@@ -10,6 +11,6 @@ def read(ctx):
     a = work.admits(ctx, MODULES)
     if not a:
         return None
-    m = flops.dims(ctx["model"])
-    need = sum(flops.min_time(*flops.prefill(m, n), ctx["peaks"])[0] for n, _ in a)
+    counts = ctx["work"]
+    need = sum(flops.min_time(*counts.prefill(n), ctx["peaks"])[0] for n, _ in a)
     return 100.0 * need / sum(s for _, s in a)

@@ -102,9 +102,15 @@ def _rope(x, pos, theta):
 
 
 class Reference:
-    """The forward pass of one configuration file's ``model`` block."""
+    """The forward pass of one configuration file's ``model`` block: the
+    dense default of bench/archs/dense.py.  ``windows`` holds each layer's
+    sliding window (None: full causal attention); here every layer takes
+    the model block's ``sliding_window``."""
 
     Q_BLOCK = 512
+    # weight rules consulted before bench/weights.py's (an architecture
+    # module's ``RULES``); the program's draw reads the same
+    RULES: dict = {}
 
     def __init__(self, model: dict, seed: int):
         self.m = model
@@ -119,7 +125,7 @@ class Reference:
         self.layers = model["num_hidden_layers"]
         self.rms = model["norm"] == "rmsnorm"
         self.eps = model["norm_eps"]
-        self.window = model.get("sliding_window")
+        self.windows = [model.get("sliding_window")] * self.layers
         self.theta = float(model["rope_theta"])
         self.bias = bool(model.get("use_bias", False))
 
@@ -149,9 +155,19 @@ class Reference:
 
     @functools.partial(jax.jit, static_argnums=0)
     def _draw_layer(self, words, layer):
-        w = weights.layer_leaves(words, self._layer_shapes(), layer)
-        return {k.rsplit("/", 1)[-1] if "/mlp/" not in k else "mlp_" + k.rsplit("/", 1)[-1]:
-                v.astype(jnp.float32) for k, v in w.items()}
+        w = weights.layer_leaves(words, self._layer_shapes(), layer, self.RULES)
+        return {k.removeprefix("layers/"): v.astype(jnp.float32) for k, v in w.items()}
+
+    def draw_layer(self, layer: int) -> dict:
+        """{program leaf path within a layer (``attn/wq``): float32 array}
+        of one layer, drawn again from the seed."""
+        return self._draw_layer(self.words, jnp.int32(layer))
+
+    def draw_top(self) -> dict:
+        """The leaves outside the layers (``embed``, ``ln_f``, ...) in
+        float32, with ``unembed`` as the (d, vocab) unembedding the forward
+        uses (the embedding's transpose where they are tied)."""
+        return self._draw_top(self.words)
 
     @functools.partial(jax.jit, static_argnums=0)
     def _draw_top(self, words):
@@ -160,7 +176,8 @@ class Reference:
         if not self.m["tie_word_embeddings"]:
             s["unembed"] = (d, vp)
         s.update({"ln_f": (d,)} if self.rms else {"ln_f_scale": (d,), "ln_f_bias": (d,)})
-        w = {p: weights.leaf(weights.key_of(words, p, -1), sh, *weights.leaf_spec(p, sh))
+        w = {p: weights.leaf(weights.key_of(words, p, -1), sh,
+                             *weights.leaf_spec(p, sh, self.RULES))
              for p, sh in s.items()}
         out = {k: v.astype(jnp.float32) for k, v in w.items()}
         out["unembed"] = (out["embed"].T if self.m["tie_word_embeddings"]
@@ -174,7 +191,7 @@ class Reference:
             return _rms(x, w[name], self.eps)
         return _layernorm(x, w[f"{name}_scale"], w[f"{name}_bias"], self.eps)
 
-    def _attend(self, q, k, v, pos):
+    def _attend(self, q, k, v, pos, window):
         """Causal (and windowed) GQA over one sequence, in query blocks."""
         p = q.shape[0]
         g = self.h // self.kv
@@ -187,8 +204,8 @@ class Reference:
             s = jnp.einsum("qkgd,tkd->kgqt", qi, k, precision=HI) * self.hd ** -0.5
             diff = pi[:, None] - pos[None, :]
             ok = diff >= 0
-            if self.window:
-                ok = ok & (diff < self.window)
+            if window:
+                ok = ok & (diff < window)
             s = jnp.where(ok[None, None], s, -jnp.inf)
             a = jax.nn.softmax(s, axis=-1)
             return jnp.einsum("kgqt,tkd->qkgd", a, v, precision=HI)
@@ -196,29 +213,29 @@ class Reference:
         out = jax.lax.map(block, (q, qpos))
         return out.reshape(p, self.h * self.hd)
 
-    @functools.partial(jax.jit, static_argnums=(0, 4))
-    def _layer(self, w, x, pos, low):
+    @functools.partial(jax.jit, static_argnums=(0, 4, 5))
+    def _layer(self, w, x, pos, low, window):
         d, h, kv, hd = self.d, self.h, self.kv, self.hd
         p = x.shape[0]
         a = self._norm(x, w, "ln1")
-        q = _mm(a, w["wq"].reshape(d, h * hd), low).reshape(p, h, hd)
-        k = _mm(a, w["wk"].reshape(d, kv * hd), low).reshape(p, kv, hd)
-        v = _mm(a, w["wv"].reshape(d, kv * hd), low).reshape(p, kv, hd)
+        q = _mm(a, w["attn/wq"].reshape(d, h * hd), low).reshape(p, h, hd)
+        k = _mm(a, w["attn/wk"].reshape(d, kv * hd), low).reshape(p, kv, hd)
+        v = _mm(a, w["attn/wv"].reshape(d, kv * hd), low).reshape(p, kv, hd)
         if self.bias:
-            q, k, v = q + w["bq"], k + w["bk"], v + w["bv"]
+            q, k, v = q + w["attn/bq"], k + w["attn/bk"], v + w["attn/bv"]
         if self.m.get("qk_norm"):
-            q = _rms(q, w["q_norm"], self.eps)
-            k = _rms(k, w["k_norm"], self.eps)
+            q = _rms(q, w["attn/q_norm"], self.eps)
+            k = _rms(k, w["attn/k_norm"], self.eps)
         q, k = _rope(q, pos, self.theta), _rope(k, pos, self.theta)
-        x = x + _mm(self._attend(q, k, v, pos), w["wo"].reshape(h * hd, d), low)
+        x = x + _mm(self._attend(q, k, v, pos, window), w["attn/wo"].reshape(h * hd, d), low)
         if self.bias:
-            x = x + w["bo"]
+            x = x + w["attn/bo"]
         a = self._norm(x, w, "ln2")
         if self.m["hidden_act"] == "silu":
-            m = jax.nn.silu(_mm(a, w["mlp_wi_gate"], low)) * _mm(a, w["mlp_wi_up"], low)
-            return x + _mm(m, w["mlp_wo"], low)
-        m = jax.nn.gelu(_mm(a, w["mlp_wi_up"], low) + w["mlp_bi"], approximate=True)
-        return x + _mm(m, w["mlp_wo"], low) + w["mlp_bo"]
+            m = jax.nn.silu(_mm(a, w["mlp/wi_gate"], low)) * _mm(a, w["mlp/wi_up"], low)
+            return x + _mm(m, w["mlp/wo"], low)
+        m = jax.nn.gelu(_mm(a, w["mlp/wi_up"], low) + w["mlp/bi"], approximate=True)
+        return x + _mm(m, w["mlp/wo"], low) + w["mlp/bo"]
 
     @functools.partial(jax.jit, static_argnums=(0, 4))
     def _logits(self, top, x, rows, low):
@@ -234,7 +251,7 @@ class Reference:
         teacher-forced sequence, padded to ``pad_to[i]`` tokens (a multiple
         of ``Q_BLOCK``, or shorter than it).  Returns a list of (n_i, vocab)
         float32 numpy arrays."""
-        top = self._draw_top(self.words)
+        top = self.draw_top()
         xs, poss = [], []
         for s, n in zip(seqs, pad_to):
             ids = np.zeros(n, np.int32)
@@ -242,8 +259,8 @@ class Reference:
             xs.append(top["embed"][jnp.asarray(ids)])
             poss.append(jnp.arange(n, dtype=jnp.int32))
         for layer in range(self.layers):
-            w = self._draw_layer(self.words, jnp.int32(layer))
-            xs = [self._layer(w, x, p, low) for x, p in zip(xs, poss)]
+            w = self.draw_layer(layer)
+            xs = [self._layer(w, x, p, low, self.windows[layer]) for x, p in zip(xs, poss)]
             del w
         out = []
         for x, s, st in zip(xs, seqs, starts):

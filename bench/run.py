@@ -63,8 +63,9 @@ def read_per_layer(cell: dict, served: dict, first: dict, peaks: dict) -> dict:
     None and its metric is left out.  A reader is handed the run's
     completions and first-token times, the reduced trace (None without
     one), the engine-clock time the profiler started (None without a
-    trace), the configuration's model and engine blocks, the number of
-    chips the program runs on, and their peaks: one chip's times that
+    trace), the configuration's model and engine blocks, the work counts of
+    its architecture (``work``: ``cell["arch"].work(model)``), the number
+    of chips the program runs on, and their peaks: one chip's times that
     number, so a share of a roofline or of the peak is of all of them."""
     n = spec.chips(cell["config"])
     ctx = {
@@ -75,6 +76,7 @@ def read_per_layer(cell: dict, served: dict, first: dict, peaks: dict) -> dict:
         "trace_opened_s": served.get("trace_opened_s"),
         "model": cell["config"]["model"],
         "engine": cell["config"]["engine"],
+        "work": cell["arch"].work(cell["config"]["model"]),
         "chips": n,
         "peaks": {k: v * n for k, v in peaks.items()},
     }
@@ -106,7 +108,6 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, device: dict,
              peaks: dict, t_start: float, *, log=_log) -> dict:
     """One run, from the weights to the result dict (without printing)."""
     from bench import check, harness
-    from bench.reference import Reference
 
     served = harness.serve(cell, seed, seconds, trace=trace, t_start=t_start, log=log)
     done = served["completions"]
@@ -139,9 +140,8 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, device: dict,
 
     t_ref = time.perf_counter()
     prompts = {r.uid: r.prompt for r in served["requests"]}
-    ref = Reference(cell["config"]["model"], seed)
-    readings = check.run_check(ref, done, prompts, seed, cell["traffic"],
-                               Reference.Q_BLOCK)
+    ref = cell["arch"].Reference(cell["config"]["model"], seed)
+    readings = check.run_check(ref, done, prompts, seed, cell["traffic"], ref.Q_BLOCK)
     readings["not_served"] = failed
     log(f"reference over {readings['checked_requests']} requests, "
         f"{readings['checked_tokens']} tokens: {time.perf_counter() - t_ref:.2f} s")

@@ -3,8 +3,12 @@
 ``root`` is a checkout's root: ``BENCHMARK.json`` beside ``bench/``.  A cell
 is an entry of ``workloads``; its configuration is
 ``bench/configs/<config>.json``, its mix ``bench/traffic/<traffic>.json``,
-its correctness limits ``bench/limits/<cell>.json``, and each per-layer
-metric ``bench/metrics/<metric>.py``.  Adding any of them is adding a file.
+its correctness limits ``bench/limits/<cell>.json``, each per-layer
+metric ``bench/metrics/<metric>.py``, and the architecture module that a
+configuration may name, ``"architecture": "bench/archs/<name>.py"``: its
+reference, weight rules, work counts and program checks
+(bench/archs/__init__.py; without the key, bench/archs/dense.py).  Adding
+any of them is adding a file.
 
 A configuration may name a device mesh, ``engine.mesh``: axis name -> size
 in the program's axis names, such as ``{"data": 1, "model": 4}``.  Its size
@@ -18,7 +22,7 @@ import json
 import math
 from pathlib import Path
 
-__all__ = ["ROOT", "load", "cell", "chips", "metric_reader"]
+__all__ = ["ROOT", "load", "cell", "chips", "metric_reader", "architecture"]
 
 ROOT = Path(__file__).resolve().parents[1]
 
@@ -33,8 +37,8 @@ def load(root: Path = ROOT) -> dict:
 
 
 def cell(name: str, root: Path = ROOT) -> dict:
-    """One cell, resolved: its entry, configuration, mix, limits and the
-    metrics it reports at each trace setting."""
+    """One cell, resolved: its entry, configuration, architecture module,
+    mix, limits and the metrics it reports at each trace setting."""
     root = Path(root)
     bench = load(root)
     entries = {w["name"]: w for w in bench["workloads"]}
@@ -60,6 +64,7 @@ def cell(name: str, root: Path = ROOT) -> dict:
         "name": name,
         "entry": w,
         "config": config,
+        "arch": architecture(config, root),
         "traffic": _json(root / "bench" / "traffic" / f"{w['traffic']}.json"),
         "limits": _json(root / "bench" / "limits" / f"{name}.json"),
         "end_to_end": e2e,
@@ -73,10 +78,28 @@ def chips(config: dict) -> int:
     return math.prod(config["engine"].get("mesh", {}).values())
 
 
-def metric_reader(name: str, root: Path = ROOT):
-    """The ``read(ctx)`` function of ``bench/metrics/<name>.py``."""
-    path = Path(root) / "bench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
+def _module(name: str, path: Path):
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name: str, root: Path = ROOT):
+    """The ``read(ctx)`` function of ``bench/metrics/<name>.py``."""
+    return _module(f"bench_metric_{name}",
+                   Path(root) / "bench" / "metrics" / f"{name}.py").read
+
+
+def architecture(config: dict, root: Path = ROOT):
+    """The architecture module a configuration names (a path under
+    ``root``), or the dense default."""
+    rel = config.get("architecture")
+    if rel is None:
+        from bench.archs import dense
+
+        return dense
+    if Path(rel).is_absolute() or ".." in Path(rel).parts:
+        raise ValueError(f"{config['name']}: architecture {rel!r} is not a path "
+                         f"under the root")
+    return _module(f"bench_arch_{Path(rel).stem}", Path(root) / rel)

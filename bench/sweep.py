@@ -59,9 +59,9 @@ def main(argv=None) -> int:
     from bench.traffic.generator import make_trace
     from repro.launch.engine import Engine, Request
 
-    cfg = harness.program_config(cell["config"])
+    cfg = harness.program_config(cell["config"], cell["arch"])
     mesh = harness.program_mesh(cell["config"])
-    params = harness.program_weights(cfg, args.seed, mesh)
+    params = harness.program_weights(cfg, args.seed, mesh, cell["arch"].RULES)
     clock = harness.chunk_clock()
     e = cell["config"]["engine"]
     eng = Engine(params, cfg, num_slots=e["num_slots"], cache_len=e["cache_len"],

@@ -22,6 +22,17 @@ give it within a factor of sqrt(2)):
   ``bias``, MLP and attention biases: 0.1, 0.02, 0.02 around 0; LayerNorm
   ``scale``: 0.1 around 1.
 
+A configuration's architecture module (bench/archs/) may bring rules of
+its own, ``RULES``: a leaf path, or its end, -> (mean, std), consulted
+before those below.  A std of ``"axis:<i>"`` is 1/sqrt(shape[i]), so a
+stack of experts ``(e, d, f)`` draws at 1/sqrt(d) under ``"axis:1"``.
+
+The program's ``layers`` are either one stacked tree (every leaf with a
+leading axis of ``n_layers``) or a list of per-layer trees, where its
+blocks differ.  A leaf's key is the same in both: (seed, the path without
+the layer index, the layer), so ``layers/attn/wq`` of layer 3 has the same
+bits either way, and a reference draws any one layer again.
+
 Attention biases (a model block with ``use_bias``, as StarCoder2's) are the
 leaves ``layers/attn/bq`` (heads, head_dim), ``layers/attn/bk`` and
 ``layers/attn/bv`` (kv_heads, head_dim), added to the q, k and v
@@ -41,7 +52,8 @@ import zlib
 import jax
 import jax.numpy as jnp
 
-__all__ = ["leaf", "levels", "leaf_spec", "layer_leaves", "program_params", "key_of", "seed_words"]
+__all__ = ["leaf", "levels", "leaf_spec", "layer_leaves", "path_and_layer", "program_params",
+           "key_of", "seed_words"]
 
 DTYPE = jnp.bfloat16
 
@@ -76,16 +88,26 @@ _RULES = {
 }
 
 
-def leaf_spec(path: str, shape) -> tuple[float, float]:
-    """(mean, std) of the leaf at ``path`` with per-layer ``shape``."""
-    name = path.rsplit("/", 1)[-1]
-    if name not in _RULES:
+def leaf_spec(path: str, shape, rules=None) -> tuple[float, float]:
+    """(mean, std) of the leaf at ``path`` with per-layer ``shape``: from
+    ``rules`` by the whole path or its end (``layers/moe/wo``, ``moe/wo``,
+    ``wo``), else from the default rules by the last component."""
+    rules = rules or {}
+    parts = path.split("/")
+    ends = ("/".join(parts[i:]) for i in range(len(parts)))
+    end = next((e for e in ends if e in rules), None)
+    if end is not None:
+        mean, rule = rules[end]
+    elif parts[-1] in _RULES:
+        mean, rule = _RULES[parts[-1]]
+    else:
         raise KeyError(f"no weight rule for {path!r}")
-    mean, rule = _RULES[name]
     if rule == "first":
         return mean, 1.0 / math.sqrt(shape[0])
     if rule == "leading":
         return mean, 1.0 / math.sqrt(math.prod(shape[:-1]))
+    if isinstance(rule, str) and rule.startswith("axis:"):
+        return mean, 1.0 / math.sqrt(shape[int(rule[5:])])
     return mean, float(rule)
 
 
@@ -132,65 +154,65 @@ def leaf(key, shape, mean: float, std: float):
     return (level * step + mean).astype(DTYPE)
 
 
-def layer_leaves(words, paths_shapes: dict, layer: int) -> dict:
+def layer_leaves(words, paths_shapes: dict, layer: int, rules=None) -> dict:
     """{path: array} for one layer's leaves, each drawn on its own key
     (traceable: ``words`` and ``layer`` may be jit operands)."""
     return {
-        p: leaf(key_of(words, p, layer), s, *leaf_spec(p, s))
+        p: leaf(key_of(words, p, layer), s, *leaf_spec(p, s, rules))
         for p, s in paths_shapes.items()
     }
 
 
-def _flatten(tree, prefix=""):
-    if isinstance(tree, dict):
-        out = {}
-        for k, v in tree.items():
-            out.update(_flatten(v, f"{prefix}{k}/"))
-        return out
-    return {prefix[:-1]: tree}
+def path_and_layer(key_path) -> tuple[str, int]:
+    """A leaf's path without the layer index, and that index (-1 where the
+    path holds none: a top-level or a stacked leaf)."""
+    parts, layer = [], -1
+    for k in key_path:
+        if isinstance(k, jax.tree_util.SequenceKey):
+            layer = k.idx
+        else:
+            parts.append(str(k.key))
+    return "/".join(parts), layer
 
 
-def _unflatten(flat: dict) -> dict:
-    root: dict = {}
-    for path, v in flat.items():
-        node = root
-        parts = path.split("/")
-        for p in parts[:-1]:
-            node = node.setdefault(p, {})
-        node[parts[-1]] = v
-    return root
-
-
-def program_params(seed: int, abstract_tree: dict, n_layers: int, shardings=None):
+def program_params(seed: int, abstract_tree: dict, n_layers: int, shardings=None,
+                   rules=None):
     """The program's parameter tree, drawn on the device in one jitted call.
 
-    ``abstract_tree`` is the tree of ShapeDtypeStructs the program expects
-    (leaves under ``layers/`` carry a leading stacked-layers axis); the
-    values come from :func:`leaf` alone.  ``shardings``, a matching tree,
-    places each leaf where the call leaves it; without it every leaf lands
-    on the default device."""
-    flat = _flatten(abstract_tree)
-    for p, s in flat.items():
+    ``abstract_tree`` is the tree of ShapeDtypeStructs the program expects:
+    its ``layers`` a stacked tree (a leading axis of ``n_layers``) or a
+    list of ``n_layers`` per-layer trees.  The values come from
+    :func:`leaf` alone, with std by ``rules`` and the defaults
+    (:func:`leaf_spec`).  ``shardings``, a matching tree, places each leaf
+    where the call leaves it; without it every leaf lands on the default
+    device."""
+    layers = abstract_tree.get("layers")
+    if isinstance(layers, list) and len(layers) != n_layers:
+        raise ValueError(f"layers: {len(layers)} listed != {n_layers} layers")
+    flat, treedef = jax.tree_util.tree_flatten_with_path(abstract_tree)
+    plan = []
+    for key_path, s in flat:
+        p, layer = path_and_layer(key_path)
         if s.dtype != DTYPE:
             raise TypeError(f"{p}: the program wants {s.dtype}, weights are {DTYPE}")
-        if p.startswith("layers/") and s.shape[0] != n_layers:
+        stacked = p.startswith("layers/") and layer < 0
+        if stacked and s.shape[0] != n_layers:
             raise ValueError(f"{p}: stacked axis {s.shape[0]} != {n_layers} layers")
+        shape = tuple(s.shape[1:] if stacked else s.shape)
+        plan.append((p, layer, stacked, shape, leaf_spec(p, shape, rules)))
 
     def make(words):
-        out = {}
-        for p, s in flat.items():
-            if p.startswith("layers/"):
-                shape = tuple(s.shape[1:])
-                mean, std = leaf_spec(p, shape)
-                out[p] = jax.vmap(
+        out = []
+        for p, layer, stacked, shape, (mean, std) in plan:
+            if stacked:
+                out.append(jax.vmap(
                     lambda i, p=p, sh=shape, m=mean, sd=std:
                         leaf(key_of(words, p, i), sh, m, sd)
-                )(jnp.arange(n_layers))
+                )(jnp.arange(n_layers)))
             else:
-                shape = tuple(s.shape)
-                out[p] = leaf(key_of(words, p, -1), shape, *leaf_spec(p, shape))
+                out.append(leaf(key_of(words, p, layer), shape, mean, std))
         return out
 
     draw = (jax.jit(make) if shardings is None
-            else jax.jit(make, out_shardings=_flatten(shardings)))
-    return _unflatten(draw(seed_words(seed)))
+            else jax.jit(make, out_shardings=treedef.flatten_up_to(shardings)))
+    return jax.tree_util.tree_unflatten(treedef, draw(seed_words(seed)))

@@ -1,7 +1,9 @@
 """A tiny benchmark root for the CPU tests: the real BENCHMARK.json with
-three toy cells added, toy configuration, mix and limit files beside it, and
-the real metric readers and peaks.  The code is the repository's ``bench``
-package; only the data files live under ``root``."""
+four toy cells added, toy configuration, mix and limit files beside it, the
+real metric readers and peaks, and a toy architecture module
+(fixtures/archs/toy_mixed.py).  The code is the repository's ``bench``
+package; only the data files and the architecture module live under
+``root``."""
 import json
 import shutil
 import sys
@@ -30,11 +32,13 @@ CODE = {"arrival": {"process": "gamma", "cv": 3.0, "rate_rps": 8.0},
 
 # Toy limits on the widest logit gap of served tokens, set like the cells'
 # (PERF.md): between the widest gap of sound runs over seeds 1-8 (toy-qwen
-# 0.0063, toy-sc 0.034, toy-sc-tp4 0.026) and the narrowest fp8-control gap
-# over seeds 1-4 (0.044, 0.27 and 0.26), on a host CPU.
+# 0.0063, toy-sc 0.034, toy-sc-tp4 0.026, toy-mixed 0.0062) and the
+# narrowest fp8-control gap over seeds 1-4 (0.044, 0.27, 0.26 and 0.056),
+# on a host CPU.
 LIMITS = {"toy-qwen.chat": {"logit_gap": 0.02, "not_served": 0},
           "toy-sc.code": {"logit_gap": 0.1, "not_served": 0},
-          "toy-sc-tp4.code": {"logit_gap": 0.1, "not_served": 0}}
+          "toy-sc-tp4.code": {"logit_gap": 0.1, "not_served": 0},
+          "toy-mixed.chat": {"logit_gap": 0.02, "not_served": 0}}
 
 # the (data 1, model 4) mesh of tests/conftest.py's four host devices
 MESH = {"data": 1, "model": 4}
@@ -62,16 +66,30 @@ def _toy_config(base: dict, name: str, **model) -> dict:
     return c
 
 
+# three sliding-window layers and one of full attention, as a
+# ``layer_types`` list and the program's ``block_pattern``; the window is
+# shorter than most of the chat mix's prompts
+MIXED_TYPES = ["sliding_attention"] * 3 + ["full_attention"]
+MIXED_PATTERN = ["window"] * 3 + ["global"]
+MIXED_WINDOW = 8
+ARCHS = Path(__file__).resolve().parent / "fixtures" / "archs"
+
+
 def make_root(tmp: Path) -> Path:
     """Write the toy root under ``tmp``; cells ``toy-qwen.chat`` (RMSNorm,
     qk-norm, tied), ``toy-sc.code`` (LayerNorm, window 32, prompts past
     the window) and ``toy-sc-tp4.code``: the same model with 8 query and 4
     KV heads, served on :data:`MESH`, so every device holds a quarter of
-    the heads, the KV heads, the MLP and the vocabulary."""
+    the heads, the KV heads, the MLP and the vocabulary; and
+    ``toy-mixed.chat``, the qwen toy with 4 layers of :data:`MIXED_TYPES`,
+    whose configuration names its architecture module,
+    ``bench/archs/toy_mixed.py``."""
     root = Path(tmp) / "root"
     (root / "bench").mkdir(parents=True)
     shutil.copytree(REPO / "bench" / "metrics", root / "bench" / "metrics")
     shutil.copy(REPO / "bench" / "peaks.json", root / "bench" / "peaks.json")
+    shutil.copytree(ARCHS, root / "bench" / "archs",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     for d in ("configs", "traffic", "limits"):
         (root / "bench" / d).mkdir()
     q = _toy_config(json.loads((REPO / "bench" / "configs" / "qwen3-4b.json").read_text()),
@@ -82,12 +100,18 @@ def make_root(tmp: Path) -> Path:
     t = _toy_config(_SC_BASE, "toy-sc-tp4", sliding_window=32, num_attention_heads=8,
                     num_key_value_heads=4)
     t["engine"] = dict(num_slots=4, cache_len=32, chunk=4, mesh=MESH)
+    x = _toy_config(q, "toy-mixed", num_hidden_layers=len(MIXED_TYPES),
+                    sliding_window=MIXED_WINDOW, layer_types=MIXED_TYPES)
+    x["program"]["overrides"]["block_pattern"] = MIXED_PATTERN
+    x["architecture"] = "bench/archs/toy_mixed.py"
+    x["engine"] = dict(num_slots=4, cache_len=64, chunk=4)
     files = {"configs/toy-qwen.json": q, "configs/toy-sc.json": s,
-             "configs/toy-sc-tp4.json": t,
+             "configs/toy-sc-tp4.json": t, "configs/toy-mixed.json": x,
              "traffic/chat.json": CHAT, "traffic/code.json": CODE,
              "limits/toy-qwen.chat.json": LIMITS["toy-qwen.chat"],
              "limits/toy-sc.code.json": LIMITS["toy-sc.code"],
-             "limits/toy-sc-tp4.code.json": LIMITS["toy-sc-tp4.code"]}
+             "limits/toy-sc-tp4.code.json": LIMITS["toy-sc-tp4.code"],
+             "limits/toy-mixed.chat.json": LIMITS["toy-mixed.chat"]}
     for rel, obj in files.items():
         (root / "bench" / rel).write_text(json.dumps(obj))
     b = json.loads((REPO / "BENCHMARK.json").read_text())
@@ -97,11 +121,15 @@ def make_root(tmp: Path) -> Path:
         {"name": "toy-sc", "source": "test", "file": "bench/configs/toy-sc.json",
          "reduced": [], "why": "toy"},
         {"name": "toy-sc-tp4", "source": "test", "file": "bench/configs/toy-sc-tp4.json",
+         "reduced": [], "why": "toy"},
+        {"name": "toy-mixed", "source": "test", "file": "bench/configs/toy-mixed.json",
          "reduced": [], "why": "toy"}]
     b["workloads"] += [
         {"name": "toy-qwen.chat", "config": "toy-qwen", "traffic": "chat", "chips": 1, "why": "toy"},
         {"name": "toy-sc.code", "config": "toy-sc", "traffic": "code", "chips": 1, "why": "toy"},
         {"name": "toy-sc-tp4.code", "config": "toy-sc-tp4", "traffic": "code", "chips": 4,
+         "why": "toy"},
+        {"name": "toy-mixed.chat", "config": "toy-mixed", "traffic": "chat", "chips": 1,
          "why": "toy"}]
     (root / "BENCHMARK.json").write_text(json.dumps(b))
     return root

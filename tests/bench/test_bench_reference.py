@@ -30,7 +30,8 @@ def root(tmp_path_factory):
     return make_root(tmp_path_factory.mktemp("ref"))
 
 
-@pytest.mark.parametrize("name", ["toy-qwen.chat", "toy-sc.code", "toy-sc-tp4.code"])
+@pytest.mark.parametrize("name", ["toy-qwen.chat", "toy-sc.code", "toy-sc-tp4.code",
+                                  "toy-mixed.chat"])
 def test_reference_redraws_the_program_weights(root, name):
     cell = spec.cell(name, root)
     diff = weight_mismatches(cell, 2**33 + 5)
@@ -124,35 +125,35 @@ def _loop_layer(w, x, m):
     ks, vs, out = [], [], []
     for t in range(x.shape[0]):
         a = ln(x[t], "ln1")
-        q = rope(np.einsum("d,dhk->hk", a, w["wq"]) + w["bq"], t)
-        ks.append(rope(np.einsum("d,dhk->hk", a, w["wk"]) + w["bk"], t))
-        vs.append(np.einsum("d,dhk->hk", a, w["wv"]) + w["bv"])
+        q = rope(np.einsum("d,dhk->hk", a, w["attn/wq"]) + w["attn/bq"], t)
+        ks.append(rope(np.einsum("d,dhk->hk", a, w["attn/wk"]) + w["attn/bk"], t))
+        vs.append(np.einsum("d,dhk->hk", a, w["attn/wv"]) + w["attn/bv"])
         seen = range(max(0, t - win + 1), t + 1)
         att = np.zeros((h, hd))
         for i in range(h):
             s = np.array([q[i] @ ks[j][i // g] for j in seen]) / np.sqrt(hd)
             p = np.exp(s - s.max())
             att[i] = sum(pj * vs[j][i // g] for pj, j in zip(p / p.sum(), seen))
-        y = x[t] + np.einsum("hk,hkd->d", att, w["wo"]) + w["bo"]
-        u = ln(y, "ln2") @ w["mlp_wi_up"] + w["mlp_bi"]
+        y = x[t] + np.einsum("hk,hkd->d", att, w["attn/wo"]) + w["attn/bo"]
+        u = ln(y, "ln2") @ w["mlp/wi_up"] + w["mlp/bi"]
         u = 0.5 * u * (1 + np.tanh(np.sqrt(2 / np.pi) * (u + 0.044715 * u ** 3)))
-        out.append(y + u @ w["mlp_wo"] + w["mlp_bo"])
+        out.append(y + u @ w["mlp/wo"] + w["mlp/bo"])
     return np.stack(out)
 
 
 def test_reference_attention_biases_match_a_per_token_loop():
     ref = Reference(BIASED, 2**32 + 3)
-    w = ref._draw_layer(ref.words, jnp.int32(1))
-    assert {"bq", "bk", "bv", "bo", "mlp_bo"} <= set(w)
+    w = ref.draw_layer(1)
+    assert {"attn/bq", "attn/bk", "attn/bv", "attn/bo", "mlp/bo"} <= set(w)
     x = np.random.default_rng(4).normal(size=(8, BIASED["hidden_size"])).astype(np.float32)
     pos = jnp.arange(8, dtype=jnp.int32)
-    got = np.asarray(ref._layer(w, jnp.asarray(x), pos, False))
+    got = np.asarray(ref._layer(w, jnp.asarray(x), pos, False, BIASED["sliding_window"]))
     want = _loop_layer(w, x.astype(np.float64), BIASED)
     # float32 rounding of sums over at most 64 terms of unit-scale values
     np.testing.assert_allclose(got, want, rtol=0, atol=1e-5 * np.abs(want).max())
     # the biases matter at that tolerance: the same weights without them differ
     plain = Reference(dict(BIASED, use_bias=False), 2**32 + 3)
-    wp = plain._draw_layer(plain.words, jnp.int32(1))
-    assert "bq" not in wp
-    without = np.asarray(plain._layer(wp, jnp.asarray(x), pos, False))
+    wp = plain.draw_layer(1)
+    assert "attn/bq" not in wp
+    without = np.asarray(plain._layer(wp, jnp.asarray(x), pos, False, BIASED["sliding_window"]))
     assert np.abs(without - want).max() > 100 * 1e-5 * np.abs(want).max()

@@ -25,6 +25,12 @@ def root(tmp_path_factory):
     return make_root(tmp_path_factory.mktemp("bench"))
 
 
+# the widest served gap of each toy cell at _run's seed, as the dense
+# reference read it before configurations could name an architecture
+# module: the dense default reads the same
+GAPS = {"toy-qwen.chat": 0.002255469560623169, "toy-sc.code": 0.009819746017456055}
+
+
 @pytest.mark.parametrize("name", ["toy-qwen.chat", "toy-sc.code"])
 def test_sound_run_is_correct(root, name):
     r = _run(root, name)
@@ -34,6 +40,7 @@ def test_sound_run_is_correct(root, name):
     assert all(v["value"] > 0 for v in r["metrics"].values())
     assert list(r)[-1] == "checked"
     assert r["checked"]["logit_gap"]["value"] <= r["checked"]["logit_gap"]["limit"]
+    assert r["checked"]["logit_gap"]["value"] == GAPS[name]
     json.dumps(r)
 
 

@@ -250,10 +250,12 @@ RECORDED_BREAKDOWN = {
 @pytest.fixture(scope="module")
 def recorded_ctx():
     from bench.run import peaks_for
+    from bench.spec import architecture
 
     config = json.loads((REPO / "bench" / "configs" / "qwen3-4b.json").read_text())
     return {"trace": json.loads(Path(FIXTURE).read_text()), "engine": config["engine"],
-            "model": config["model"], "peaks": peaks_for("TPU v5 lite", REPO),
+            "model": config["model"], "work": architecture(config, REPO).work(config["model"]),
+            "peaks": peaks_for("TPU v5 lite", REPO),
             "completions": {}, "first": {}, "records": [], "trace_opened_s": None}
 
 
