@@ -129,6 +129,7 @@ from repro.launch.journal import (
     replay_unit_levels,
 )
 from repro.launch.telemetry import HostSpans, Telemetry
+from repro.layers import attention as attn
 from repro.models import lm
 from repro.models.config import ModelConfig
 
@@ -404,6 +405,38 @@ def _ticket_from_record(rec: dict, *, arrival_s: float = 0.0) -> _Ticket:
     return _Ticket(req, trips=int(rec.get("trips", 0)))
 
 
+def _kv_block_geometry(cfg, cache_len: int, dtype) -> dict:
+    """{(cache lines, t-block, ring): attention layers} of a pool, with the
+    t-block the decode-attention kernel resolves for each cache."""
+    from repro.kernels.attention.ops import resolved_lines
+
+    geom: dict = {}
+    for block in cfg.blocks:
+        if block in ("global", "window"):
+            lines = cache_len if block == "global" else min(cache_len, cfg.window)
+            key = (lines, resolved_lines(cfg.n_heads, lines, cfg.n_kv_heads,
+                                         cfg.d_head, dtype), block == "window")
+            geom[key] = geom.get(key, 0) + 1
+    return geom
+
+
+def _kv_blocks_fetched(geom: dict, emitted, pos_end):
+    """(fetched, whole) K blocks over one decode chunk's steps and layers.
+    A row is live at the steps it emitted, which are a prefix of the chunk,
+    and step i of a live row reads up to its start position plus i."""
+    from repro.kernels.attention.ops import blocks_fetched
+
+    emitted = np.asarray(emitted, bool)
+    start = np.asarray(pos_end) - emitted.sum(axis=1)
+    pos = start[:, None] + np.arange(emitted.shape[1])[None, :]
+    fetched = whole = 0
+    for (lines, block, wrap), n_layers in geom.items():
+        f, w = blocks_fetched(pos, emitted, lines, block, wrap)
+        fetched += n_layers * f
+        whole += n_layers * w
+    return fetched, whole
+
+
 class Engine:
     """Slot-pool scheduler around the jitted admit / decode-chunk steps.
 
@@ -610,6 +643,15 @@ class Engine:
         draft_on = self._draft_model is not None
         if draft_on:
             dparams, dcfg = self._draft_model
+        # the pool's attention layers by (cache lines, t-block, ring), where
+        # decode attention takes the blocked kernel: the host counts the
+        # blocks it fetches per chunk (stats/telemetry kv_blocks_read_share)
+        self._kv_geom = None
+        cache_dtype = jnp.int8 if quantized_kv else jnp.dtype(cfg.act_dtype)
+        route = (cfg.decode_kernel if mesh is not None
+                 else attn.decode_route(cfg, cache_dtype))
+        if route is not None and not spec_on:
+            self._kv_geom = _kv_block_geometry(cfg, cache_len, cache_dtype)
 
         def make_admit(acfg):
             """Build the jitted admission step for one datapath config.
@@ -1336,8 +1378,14 @@ class Engine:
         # (separate np.asarray round-trips measurably dominate the
         # smoke-scale serve loop)
         with self._spans.span("decode_sync"):
-            return jax.device_get((toks, emitted, self._active, bad, mx,
-                                   cc, cd, cmr, crs))
+            if self._kv_geom is None:
+                return jax.device_get((toks, emitted, self._active, bad, mx,
+                                       cc, cd, cmr, crs))
+            # the chunk's end positions ride the same sync, for the block count
+            got = jax.device_get((toks, emitted, self._active, bad, mx,
+                                  cc, cd, cmr, crs, self._pos))
+        self._kv_chunk = _kv_blocks_fetched(self._kv_geom, got[1], got[9])
+        return got[:9]
 
     def _decode_chunk_spec(self):
         """The speculative twin of :meth:`_decode_chunk`: one jitted
@@ -1574,6 +1622,7 @@ class Engine:
             "promotions": 0,
         }
         spans = self._spans = HostSpans()
+        kv_fetched = kv_whole = 0
         with spans.span("run") as t0, spans.collect_gc():
             decode_chunks = 0
             if self.spec is not None:
@@ -1684,6 +1733,9 @@ class Engine:
                     self._decode_chunk()
                 )
                 turn_from = time.perf_counter()
+                if self._kv_geom is not None:
+                    kv_fetched += self._kv_chunk[0]
+                    kv_whole += self._kv_chunk[1]
                 decode_chunks += 1
                 self._chunks_total += 1
                 now = chunk_t = turn_from - t0
@@ -1766,6 +1818,9 @@ class Engine:
                             "canary_divergences": int(np.sum(cd)),
                             "canary_max_rel": float(np.max(cmr)) if len(cmr) else 0.0,
                             "unit_levels": hist,
+                            "kv_blocks_read_share": (
+                                None if self._kv_geom is None
+                                else self._kv_chunk[0] / self._kv_chunk[1]),
                         })
                 # autosave at the chunk boundary, after the host bookkeeping
                 # above — the durable cut the kill-and-resume chaos suite
@@ -1819,6 +1874,10 @@ class Engine:
             **{f"host_{k}_s": spans.seconds.get(k, 0.0) for k in HOST_SPANS},
             "longest_turn_s": longest_turn,
             "longest_turn_chunk": float(longest_turn_chunk),
+            # K/V blocks decode attention fetched over what reading every
+            # row's whole cache would: None where it reads the cache inline
+            "kv_blocks_read_share": (
+                None if self._kv_geom is None else kv_fetched / max(kv_whole, 1)),
         }
         if self.spec is not None:
             acc = self._spec_acc_total - spec_acc0

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from repro.distributed.constraints import constrain, shard_count
+from repro.distributed.constraints import constrain, current_rules, shard_count
 from repro.layers.norms import rmsnorm, rmsnorm_select
 from repro.layers.param import DenseInit, zeros
 from repro.layers.rope import apply_rope
@@ -26,6 +26,7 @@ __all__ = [
     "attention_prefill",
     "attention_decode",
     "attention_verify",
+    "decode_route",
     "verify_cache_commit",
     "init_kv_cache",
     "kv_cache_specs",
@@ -488,8 +489,32 @@ def attention_prefill(p, cfg, x, cache, positions, *, window: Optional[int] = No
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype)), cache
 
 
+def decode_route(cfg, cache_dtype, kernel: Optional[str] = None) -> Optional[str]:
+    """The route of cached decode attention: "fused" (the blocked Pallas
+    kernel, ``kernels/attention``), "reference" (its jnp oracle) or None
+    (the inline XLA block).  ``kernel``, else ``cfg.decode_kernel``, forces
+    one.  Otherwise the kernel serves where it compiles and reads the cache
+    as it lies: the dispatch layer resolves the compiled backend (a TPU),
+    the cache holds floats (an int8 cache folds its scales inline), and no
+    mesh rules scope is active (a sharded cache would need a shard_map)."""
+    kernel = kernel if kernel is not None else getattr(cfg, "decode_kernel", None)
+    if kernel is not None:
+        if kernel not in ("fused", "reference"):
+            raise ValueError(
+                f"unknown decode kernel {kernel!r}; expected 'fused' or 'reference'"
+            )
+        return kernel
+    from repro.kernels import dispatch
+
+    if (dispatch.resolve_backend() == "compiled"
+            and jnp.issubdtype(cache_dtype, jnp.floating)
+            and current_rules() is None):
+        return "fused"
+    return None
+
+
 def _decode_attend(cfg, x, q, k_new, v_new, cache, pos, *, window, layer_idx,
-                   kernel):
+                   kernel, active):
     """The cached part of :func:`attention_decode`, between the q/k/v and
     output projections: write the new token's K/V line, read the cache back
     and attend over it.  Returns (per-head out (b, 1, h, hd), new cache)."""
@@ -502,7 +527,6 @@ def _decode_attend(cfg, x, q, k_new, v_new, cache, pos, *, window, layer_idx,
     # this is just ``pos``
     slot = jnp.asarray(pos % cache_len, jnp.int32)
     write = _cache_update_slots if per_slot else _cache_update
-    k_scale = v_scale = None
     if quantized:
         kq, ks = _quantize_kv(k_new[:, 0])
         vq, vs = _quantize_kv(v_new[:, 0])
@@ -512,6 +536,32 @@ def _decode_attend(cfg, x, q, k_new, v_new, cache, pos, *, window, layer_idx,
             "k_scale": write(cache["k_scale"], ks, slot, layer_idx),
             "v_scale": write(cache["v_scale"], vs, slot, layer_idx),
         }
+    else:
+        cache = {
+            "k": write(cache["k"], k_new[:, 0], slot, layer_idx),
+            "v": write(cache["v"], v_new[:, 0], slot, layer_idx),
+        }
+
+    route = decode_route(cfg, cache["k"].dtype, kernel)
+    if route is not None:
+        # the kernel reads the written buffers as they lie, stacked or per
+        # layer, and builds the validity mask from per-row positions; the
+        # scalar lock-step case broadcasts to the per-slot form
+        from repro.kernels.attention import ops as attn_kernel
+
+        fn = (attn_kernel.ref_decode_attention if route == "reference"
+              else attn_kernel.decode_attention)
+        scales = ((cache["k_scale"], cache["v_scale"]) if quantized
+                  else (None, None))
+        pos_b = pos if per_slot else jnp.broadcast_to(pos, (b,))
+        out = fn(
+            q[:, 0], cache["k"], cache["v"], pos_b, *scales, layer_idx, active,
+            scale=cfg.d_head**-0.5, wrap=bool(window),
+        )[:, None]
+        return out, cache
+
+    k_scale = v_scale = None
+    if quantized:
         # scales are FOLDED into the scores / attention weights rather than
         # materializing a dequantized cache copy (saves 2 full-cache HBM
         # passes per layer; on TPU the int8->bf16 convert fuses into the
@@ -521,33 +571,8 @@ def _decode_attend(cfg, x, q, k_new, v_new, cache, pos, *, window, layer_idx,
         k_scale = _cache_read(cache["k_scale"], layer_idx)  # (b, t, kv)
         v_scale = _cache_read(cache["v_scale"], layer_idx)
     else:
-        cache = {
-            "k": write(cache["k"], k_new[:, 0], slot, layer_idx),
-            "v": write(cache["v"], v_new[:, 0], slot, layer_idx),
-        }
         k = _cache_read(cache["k"], layer_idx)
         v = _cache_read(cache["v"], layer_idx)
-
-    kernel = kernel if kernel is not None else getattr(cfg, "decode_kernel", None)
-    if kernel:
-        # fused Pallas route (docs/kernels.md): the validity mask is built
-        # in-kernel from per-row positions, so only ``pos`` crosses the
-        # boundary; the scalar lock-step case broadcasts to the per-slot form
-        # (identical mask rows, identical math)
-        from repro.kernels.attention import ops as attn_kernel
-
-        if kernel not in ("fused", "reference"):
-            raise ValueError(
-                f"unknown decode kernel {kernel!r}; expected 'fused' or 'reference'"
-            )
-        fn = (attn_kernel.ref_decode_attention if kernel == "reference"
-              else attn_kernel.decode_attention)
-        pos_b = pos if per_slot else jnp.broadcast_to(pos, (b,))
-        out = fn(
-            q[:, 0], k, v, pos_b, k_scale, v_scale,
-            scale=cfg.d_head**-0.5, wrap=bool(window),
-        )[:, None]
-        return out, cache
 
     # mask out unwritten slots: before the ring wraps only slots <= pos hold
     # tokens (treating unwritten zero-K slots as valid leaks exp(0) mass
@@ -574,7 +599,7 @@ def _decode_attend(cfg, x, q, k_new, v_new, cache, pos, *, window, layer_idx,
 
 def attention_decode(p, cfg, x, cache, pos, *, window: Optional[int] = None,
                      layer_idx=None, kernel: Optional[str] = None,
-                     norm_levels=None):
+                     norm_levels=None, active=None):
     """Single-token decode. x: (b, 1, d); cache holds ``cache_len`` slots.
 
     ``pos`` is either a scalar (lock-step batch: every row at the same
@@ -586,11 +611,18 @@ def attention_decode(p, cfg, x, cache, pos, *, window: Optional[int] = None,
     With ``layer_idx``, cache tensors carry a leading stacked-layers axis and
     are updated in place (see _cache_update).  Returns (out, new_cache).
 
-    ``kernel`` routes the scored-attention block (defaults to
-    ``cfg.decode_kernel``): None keeps the inline XLA path; "fused" runs the
-    Pallas decode-attention kernel via the dispatch layer; "reference" runs
-    the kernel's pure-jnp oracle (same math, useful for bisecting).  The
-    projections, cache write and wo projection are identical on every route.
+    The scored attention takes the route :func:`decode_route` picks:
+    ``kernel`` (defaults to ``cfg.decode_kernel``) forces "fused", the
+    blocked Pallas kernel via the dispatch layer, or "reference", its
+    pure-jnp oracle (same blocks, same arithmetic); left unset, the kernel
+    serves on a TPU for a float cache outside any mesh rules scope, and
+    the inline XLA block everywhere else.  The projections, cache write
+    and wo projection are identical on every route.
+
+    ``active`` ((b,) bool, optional): rows that are not live.  On the
+    kernel route they read no cache block and attend to zeros (their
+    logits are discarded by the slot scheduler); None means every row is
+    live.  The inline path reads every row as before.
 
     ``norm_levels`` (accuracy-SLO serving, (b,) int32): per-slot ladder rung
     for the qk-norm rsqrt when ``cfg.sqrt_ladder`` is set; None keeps the
@@ -621,7 +653,7 @@ def attention_decode(p, cfg, x, cache, pos, *, window: Optional[int] = None,
     with jax.named_scope("decode_attention"):
         out, cache = _decode_attend(
             cfg, x, q, k_new, v_new, cache, pos, window=window,
-            layer_idx=layer_idx, kernel=kernel,
+            layer_idx=layer_idx, kernel=kernel, active=active,
         )
     out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
     return out, cache

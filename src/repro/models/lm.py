@@ -455,14 +455,16 @@ def init_cache(
 # ---------------------------------------------------------------------------
 
 
-def _layer_decode(p, cfg, block, x, cache, pos, *, cross_kv=None, layer_idx=None, levels=None):
+def _layer_decode(p, cfg, block, x, cache, pos, *, cross_kv=None, layer_idx=None, levels=None,
+                  active=None):
     """One decoder layer step.  With ``layer_idx`` the cache tree is the full
     stacked (L, ...) carry and only this layer's line is touched (in-place
     DUS — the production decode pattern: per-step HBM traffic is one layer
     read + one token write, not a cache re-materialization).
 
     ``levels`` (accuracy-SLO serving): per-row ladder rung for every norm
-    rsqrt in the layer, including the qk-norm inside attention_decode."""
+    rsqrt in the layer, including the qk-norm inside attention_decode.
+    ``active`` ((b,) bool or None): the live rows, for attention_decode."""
     if block in ("global", "window"):
         h = _norm(p, "ln1", x, cfg, levels)
         h, cache = attn.attention_decode(
@@ -470,6 +472,7 @@ def _layer_decode(p, cfg, block, x, cache, pos, *, cross_kv=None, layer_idx=None
             window=cfg.window if block == "window" else None,
             layer_idx=layer_idx,
             norm_levels=levels,
+            active=active,
         )
         x = x + h
         if cross_kv is not None:
@@ -496,7 +499,8 @@ def _layer_decode(p, cfg, block, x, cache, pos, *, cross_kv=None, layer_idx=None
     return x, cache
 
 
-def decode_step(params, cfg: ModelConfig, cache, tokens, pos, *, cross_kv=None, unit_levels=None):
+def decode_step(params, cfg: ModelConfig, cache, tokens, pos, *, cross_kv=None, unit_levels=None,
+                active=None):
     """One decode forward (a single token per batch row) over the cache.
 
     tokens: (b, 1) int32; pos: int32 position of this token — a scalar
@@ -512,6 +516,11 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, pos, *, cross_kv=None, 
     ``unit_levels`` ((b,) int32, requires ``cfg.sqrt_ladder``): accuracy-SLO
     serving — every norm rsqrt (layer norms, qk-norm, final norm) routes each
     row through its ladder rung; None keeps the single-datapath trace.
+
+    ``active`` ((b,) bool, slot-scheduled serving): the rows that hold a
+    live request.  Where decode attention takes the blocked kernel, a row
+    that is not live reads no cache block (its logits are the caller's to
+    discard); None means every row is live, as in lock-step decode.
 
     Returns (logits (b, 1, vocab), new_cache).
     """
@@ -538,7 +547,7 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, pos, *, cross_kv=None, 
                 p, ckv, i = layer
                 x, c = _layer_decode(
                     p, cfg, blocks[0], x, c, pos, cross_kv=ckv, layer_idx=i,
-                    levels=unit_levels,
+                    levels=unit_levels, active=active,
                 )
                 return (x, c), None
 
@@ -551,7 +560,8 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, pos, *, cross_kv=None, 
                 x, c = carry
                 p, i = layer
                 x, c = _layer_decode(
-                    p, cfg, blocks[0], x, c, pos, layer_idx=i, levels=unit_levels
+                    p, cfg, blocks[0], x, c, pos, layer_idx=i, levels=unit_levels,
+                    active=active,
                 )
                 return (x, c), None
 
@@ -559,7 +569,8 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, pos, *, cross_kv=None, 
     else:
         new_cache = []
         for p, b, c in zip(params["layers"], blocks, cache):
-            x, c = _layer_decode(p, cfg, b, x, c, pos, cross_kv=cross_kv, levels=unit_levels)
+            x, c = _layer_decode(p, cfg, b, x, c, pos, cross_kv=cross_kv, levels=unit_levels,
+                                 active=active)
             new_cache.append(c)
 
     x = _norm(params, "ln_f", x, cfg, unit_levels)
@@ -886,8 +897,10 @@ def decode_slots_scan(params, cfg: ModelConfig, cache, tok, pos, active,
     ``temperature`` > 0 and expected to be request-derived (slot-index keys
     would tie a request's samples to its slot placement — the Engine passes
     uid-keyed streams).  Inactive slots re-feed their last token at a
-    frozen position — their logits are discarded, their emissions masked, and
-    row-wise math keeps them from perturbing live slots, so a staggered slot
+    frozen position — their logits are discarded, their emissions masked
+    (on the decode-attention kernel route, ``active`` spares them every
+    cache read), and row-wise math keeps them from perturbing live slots, so
+    a staggered slot
     decodes bit-identically to a solo :func:`generate_scan` of the same
     request (greedy, non-MoE).
 
@@ -977,7 +990,8 @@ def decode_slots_scan(params, cfg: ModelConfig, cache, tok, pos, active,
         if canary:
             cc, cd, cmr, crs = carry[tail:tail + 4]
         logits, new_cache = decode_step(
-            params, cfg, cache, tok, pos, cross_kv=cross_kv, unit_levels=unit_levels
+            params, cfg, cache, tok, pos, cross_kv=cross_kv, unit_levels=unit_levels,
+            active=active,
         )
         lg = logits[:, -1].astype(jnp.float32)
         if logits_hook is not None:
@@ -988,8 +1002,9 @@ def decode_slots_scan(params, cfg: ModelConfig, cache, tok, pos, active,
             def shadow(op):
                 # reads the PRE-step cache (the same read the served step
                 # saw); the shadow's own cache write is dropped on the floor
-                c, t, p, served = op
-                el, _ = decode_step(params, ecfg, c, t, p, cross_kv=cross_kv)
+                c, t, p, served, act = op
+                el, _ = decode_step(params, ecfg, c, t, p, cross_kv=cross_kv,
+                                    active=act)
                 el = el[:, -1].astype(jnp.float32)
                 agree = jnp.argmax(served, axis=-1) == jnp.argmax(el, axis=-1)
                 ed = jnp.abs(served - el)
@@ -1007,7 +1022,7 @@ def decode_slots_scan(params, cfg: ModelConfig, cache, tok, pos, active,
             # the whole vocab-wide reduction lives INSIDE the cond: a
             # non-canary step pays only the scalar predicate, not O(vocab)
             agree, rel, red = jax.lax.cond(
-                fire, shadow, no_shadow, (cache, tok, pos, lg)
+                fire, shadow, no_shadow, (cache, tok, pos, lg, active)
             )
             upd = fire & active
             cc = cc + upd.astype(jnp.int32)

@@ -242,3 +242,44 @@ def test_sampling_path_runs_and_is_deterministic():
     a, b = run_once(), run_once()
     np.testing.assert_array_equal(a, b)
     assert a.min() >= 0 and a.max() < cfg.vocab and len(a) == 6
+
+
+def test_engine_kernel_route_matches_solo_and_counts_blocks(monkeypatch):
+    """``Engine`` on the decode-attention kernel route (forced; interpret
+    mode here) with 8-line t-blocks over a 32-line cache: three requests
+    share two slots, so the third is admitted into a freed slot mid-run,
+    and each emits exactly its solo run on the same route.
+    ``kv_blocks_read_share`` is the hand count for this schedule, per
+    layer (4 blocks a row, 2 rows, 4 steps a chunk):
+
+    chunk 1: A at 5, 6, 7, 8 -> 1+1+1+2; B at 11, 12, 13, then done -> 2+2+2
+    chunk 2: A at 9, 10 -> 2+2; C (admitted into B's slot) at 7..10 -> 1+2+2+2
+    chunk 3: C at 11 -> 2; three steps with no live row fetch one block each
+
+    27 of 3 chunks x 4 steps x 2 rows x 4 blocks = 96."""
+    import dataclasses
+
+    from repro.kernels import dispatch
+    from repro.launch.engine import Engine, Request
+
+    spec = dispatch.get("decode_attention")
+    monkeypatch.setitem(dispatch._REGISTRY, "decode_attention", dataclasses.replace(
+        spec, tiling=dispatch.TilingSpec(default=(8,), candidates=((8,),))))
+    cfg, params = _setup("qwen3-4b")
+    fused = cfg.replace(decode_kernel="fused").validate()
+    prompts = {uid: jax.random.randint(jax.random.key(uid + 1), (n,), 0, cfg.vocab)
+               for uid, n in ((0, 5), (1, 11), (2, 7))}
+    budgets = {0: 6, 1: 3, 2: 5}
+    eng = Engine(params, fused, num_slots=2, cache_len=32, chunk=4)
+    done = eng.run([Request(uid=u, prompt=np.asarray(p), max_new_tokens=budgets[u],
+                            arrival_s=0.0) for u, p in prompts.items()])
+    for uid, prompt in prompts.items():
+        solo = _solo(params, fused, prompt[None], budgets[uid])
+        np.testing.assert_array_equal(done[uid].tokens, solo)
+    assert eng.stats["decode_chunks"] == 3
+    assert eng.stats["kv_blocks_read_share"] == 27 / 96
+    # the inline route reads every row whole and reports no share
+    inline = Engine(params, cfg, num_slots=2, cache_len=32, chunk=4)
+    inline.run([Request(uid=0, prompt=np.asarray(prompts[0]), max_new_tokens=2,
+                        arrival_s=0.0)])
+    assert inline.stats["kv_blocks_read_share"] is None
